@@ -17,7 +17,6 @@ from pathlib import Path
 from typing import Any, Callable
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 Pytree = Any
@@ -74,8 +73,9 @@ class AsyncSaver:
 
 def restore(path: str | Path, like: Pytree, *,
             shardings: Pytree | None = None) -> tuple[Pytree, dict]:
-    """Restore into the structure of ``like``; place under ``shardings``
-    (the *new* mesh's sharding tree — elastic resharding)."""
+    """Restore into the structure and dtypes of ``like`` (arrays or
+    ``jax.ShapeDtypeStruct``s, e.g. ``abstract_train_state``); place under
+    ``shardings`` (the *new* mesh's sharding tree — elastic resharding)."""
     path = Path(path)
     manifest = json.loads((path / "manifest.json").read_text())
     data = np.load(path / "arrays.npz")
@@ -86,7 +86,7 @@ def restore(path: str | Path, like: Pytree, *,
                         for q in p)
         arr = data[key]
         if hasattr(leaf, "dtype") and arr.dtype != leaf.dtype:
-            arr = np.asarray(jnp.asarray(arr).astype(leaf.dtype))
+            arr = arr.astype(leaf.dtype)
         out.append(arr)
     tree = jax.tree_util.tree_unflatten(treedef, out)
     if shardings is not None:

@@ -2,10 +2,11 @@
 heterogeneous, dynamic clusters via multi-edge topology modelling, a
 discrete-event simulator cost model, and parallel branch-and-bound search."""
 
-from .cluster import (DEVICE_PROFILES, ClusterTopology, DeviceInstance,
-                      DeviceSpec, Edge, MultiEdgeLink, NetworkEvent,
-                      dgx_h100_node, hetero_cluster, homogeneous_cluster,
-                      multi_pod_tpu, tpu_pod)
+from .cluster import (DEVICE_KINDS, DEVICE_PROFILES, ClusterTopology,
+                      DeviceInstance, DeviceSpec, Edge, MultiEdgeLink,
+                      NetworkEvent, dgx_h100_node, hetero_cluster,
+                      homogeneous_cluster, multi_pod_tpu,
+                      profile_for_device_kind, tpu_pod)
 from .costmodel import (MeshCollectiveModel, allreduce_time, collective_time,
                         graph_compute_lower_bound, op_time, transfer_time)
 from .dynamic import (AdaptationRecord, DynamicOrchestrator, PlanTemplates,

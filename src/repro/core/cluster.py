@@ -98,6 +98,22 @@ DEVICE_INTRA_BW: dict[str, tuple[float, str]] = {
     "TPUv5e": (100 * GB, "ici"),
 }
 
+# ``jax.Device.device_kind`` -> DEVICE_PROFILES key, for devices JAX can run
+# on.  A TPU v5e reports "TPU v5 lite".
+DEVICE_KINDS: dict[str, str] = {
+    "TPU v5 lite": "TPUv5e",
+}
+
+
+def profile_for_device_kind(kind: str) -> str:
+    """The DEVICE_PROFILES key of a JAX ``device_kind``; an unknown kind is
+    an error, never a default."""
+    try:
+        return DEVICE_KINDS[kind]
+    except KeyError:
+        raise KeyError(f"no device profile for device_kind {kind!r}; "
+                       f"known kinds: {sorted(DEVICE_KINDS)}") from None
+
 
 @dataclass
 class DeviceInstance:

@@ -3,19 +3,21 @@
 TPU-native adaptation of the paper's fusion example (§2.3, FlashAttention):
 instead of a CUDA warp-level design, tiling follows the TPU memory hierarchy:
 
+  * the call takes the model's (B, S, H, hd) layout and runs the kernel on
+    head-major (B, H, S, hd) views, so every block is (1, 1, rows, hd):
+    its last two axes are a multiple-of-8 sequence block and the full
+    head_dim, which is what the TPU lowering accepts,
   * grid = (batch, q_heads, q_blocks, kv_blocks); the minor-most kv_blocks
     dimension iterates sequentially on a TensorCore, so fp32 running
-    (acc, m, l) live in VMEM scratch across kv steps,
-  * BlockSpecs stream (block_q × head_dim) / (block_kv × head_dim) tiles
-    HBM→VMEM; head_dim rides the 128-lane minor dimension and block sizes
-    are MXU-aligned multiples of 128,
+    (acc, m, l) live in 2-D VMEM scratch across kv steps,
   * GQA is free: the kv BlockSpec index_map sends q-head h to kv-head
     h // (H // KV) — no repeated-KV materialization,
   * the S×S score matrix never touches HBM (the whole point).
 
 Numerics follow the standard stable online softmax; the causal/window mask
 is applied per tile from block-relative iotas.  Validated on CPU with
-``interpret=True`` against ``ref.mha_reference`` (tests/test_kernels.py).
+``interpret=True`` against ``ref.mha_reference`` (tests/test_kernels.py) and
+compiled for a described TPU v5e in tests/test_tpu_compile.py.
 """
 
 from __future__ import annotations
@@ -45,9 +47,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)           # (bq, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)           # (bkv, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    q = q_ref[0, 0].astype(jnp.float32)                 # (bq, hd)
+    k = k_ref[0, 0].astype(jnp.float32)                 # (bkv, hd)
+    v = v_ref[0, 0].astype(jnp.float32)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     if softcap:
@@ -65,13 +67,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         masked = jnp.where(qpos - kpos < window, masked, NEG_INF)
     s = masked
 
-    m_prev = m_ref[...]
-    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
+    m_prev = m_ref[...]                                 # (bq, 1)
+    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_cur)
-    p = jnp.exp(s - m_cur[:, None])
+    p = jnp.exp(s - m_cur)
     p = jnp.where(s <= NEG_INF / 2, 0.0, p)   # fully-masked rows stay zero
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     m_ref[...] = m_cur
@@ -79,7 +81,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     @pl.when(ik == nk - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.custom_vjp,
@@ -152,24 +154,25 @@ def _flash_fwd_kernel_call(q: jax.Array, k: jax.Array, v: jax.Array, *,
         _flash_kernel, scale=scale, causal=causal, window=window,
         block_q=bq, block_kv=bkv, seq_q=Sq, seq_kv=Skv, softcap=softcap)
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, 1, hd),
-                         lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, bkv, 1, hd),
-                         lambda b, h, iq, ik: (b, ik, h // G, 0)),
-            pl.BlockSpec((1, bkv, 1, hd),
-                         lambda b, h, iq, ik: (b, ik, h // G, 0)),
+            pl.BlockSpec((1, 1, bq, hd),
+                         lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, bkv, hd),
+                         lambda b, h, iq, ik: (b, h // G, ik, 0)),
+            pl.BlockSpec((1, 1, bkv, hd),
+                         lambda b, h, iq, ik: (b, h // G, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, hd),
-                               lambda b, h, iq, ik: (b, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sq, H, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, bq, hd),
+                               lambda b, h, iq, ik: (b, h, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, hd), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(*(x.transpose(0, 2, 1, 3) for x in (q, k, v)))
+    return out.transpose(0, 2, 1, 3)

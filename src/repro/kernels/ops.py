@@ -1,41 +1,14 @@
-"""Public kernel entry points: pick Pallas-TPU or interpret/reference.
+"""Public kernel entry points: the compiled Pallas TPU kernels.
 
-``flash_attention`` / ``rmsnorm`` dispatch on the backend: compiled Pallas on
-TPU, ``interpret=True`` (Python-executed kernel body) on CPU so the same
-call sites validate everywhere.  The model layer can route its attention
-through here when ``ArchConfig.use_flash_kernel`` is set (the fused
-cost-model entry of paper §2.3).
+``flash_attention`` / ``rmsnorm`` run the compiled Pallas kernel
+(``interpret=False`` by default).  A caller without a TPU passes
+``interpret=True`` itself (the CPU tests do), which executes the kernel body
+in Python; nothing switches to it behind the caller's back.  No model layer
+calls these yet: ``layers.mha`` and ``layers.rms_norm`` use their jnp forms.
 """
 
-from __future__ import annotations
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.ref import mha_reference, rmsnorm_reference
+from repro.kernels.rmsnorm import rmsnorm
 
-import jax
-
-from repro.kernels import ref
-from repro.kernels.flash_attention import flash_attention as _flash
-from repro.kernels.rmsnorm import rmsnorm as _rmsnorm
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    softcap: float = 0.0, block_q: int = 256,
-                    block_kv: int = 256, interpret: bool | None = None):
-    if interpret is None:
-        interpret = not _on_tpu()
-    return _flash(q, k, v, causal=causal, window=window, softcap=softcap,
-                  block_q=block_q, block_kv=block_kv, interpret=interpret)
-
-
-def rmsnorm(x, w, *, eps: float = 1e-6, block_rows: int = 256,
-            interpret: bool | None = None):
-    if interpret is None:
-        interpret = not _on_tpu()
-    return _rmsnorm(x, w, eps=eps, block_rows=block_rows,
-                    interpret=interpret)
-
-
-mha_reference = ref.mha_reference
-rmsnorm_reference = ref.rmsnorm_reference
+__all__ = ["flash_attention", "rmsnorm", "mha_reference", "rmsnorm_reference"]

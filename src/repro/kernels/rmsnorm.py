@@ -2,7 +2,10 @@
 
 Grid tiles rows (tokens); the feature dim rides the 128-lane minor axis in
 one VMEM block (d_model ≤ a few K fits comfortably).  fp32 accumulation for
-the mean-square reduction regardless of input dtype.
+the mean-square reduction regardless of input dtype.  The row block is the
+whole row count or a multiple of 8 (the TPU sublane tiling); a row count it
+does not divide gets a partial last block, whose out-of-range rows are never
+written back.
 """
 
 from __future__ import annotations
@@ -32,12 +35,10 @@ def rmsnorm(x: jax.Array, w: jax.Array, *, eps: float = 1e-6,
     for s in shape[:-1]:
         rows *= s
     x2 = x.reshape(rows, d)
-    br = min(block_rows, rows)
-    while rows % br:
-        br -= 1
+    br = rows if rows <= block_rows else max(8, block_rows // 8 * 8)
     out = pl.pallas_call(
         functools.partial(_rmsnorm_kernel, eps=eps),
-        grid=(rows // br,),
+        grid=(pl.cdiv(rows, br),),
         in_specs=[pl.BlockSpec((br, d), lambda i: (i, 0)),
                   pl.BlockSpec((d,), lambda i: (0,))],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),

@@ -175,8 +175,6 @@ def probe_costs(cfg: ArchConfig, shape: ShapeSpec, mesh, prof, *,
         lowered = build_lowered(sub, shape, mesh, prof, microbatches=1,
                                 donate=False, unroll=True, remat=remat)
         ca = lowered.compile().cost_analysis()
-        if isinstance(ca, (list, tuple)):   # jax<=0.4.x returns [dict]
-            ca = ca[0] if ca else {}
         return {"flops": float(ca.get("flops", 0.0)),
                 "bytes": float(ca.get("bytes accessed", 0.0))}
 

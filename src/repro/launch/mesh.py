@@ -18,10 +18,11 @@ def _mk(shape, axes) -> Mesh:
     n = math.prod(shape)
     devs = jax.devices()
     if len(devs) < n:
-        raise RuntimeError(
-            f"mesh {shape} needs {n} devices, found {len(devs)} — the "
-            "dry-run launcher must set XLA_FLAGS="
-            "--xla_force_host_platform_device_count before importing jax")
+        hint = (" (on the CPU, set XLA_FLAGS="
+                "--xla_force_host_platform_device_count before importing "
+                "jax)" if devs[0].platform == "cpu" else "")
+        raise RuntimeError(f"mesh {shape} needs {n} devices, found "
+                           f"{len(devs)} {devs[0].platform} devices{hint}")
     return Mesh(np.asarray(devs[:n]).reshape(shape), axes)
 
 

@@ -401,8 +401,6 @@ def build_report(*, arch: str, shape: ShapeSpec, mesh_name: str,
                  note: str = "") -> RooflineReport:
     chips = math.prod(mesh_shape.values())
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):       # jax<=0.4.x returns [dict]
-        ca = ca[0] if ca else {}
     flops_static = float(ca.get("flops", 0.0))
     bytes_static = float(ca.get("bytes accessed", 0.0))
     if probe is not None:

@@ -17,9 +17,8 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from repro.parallel.compat import shard_map
 
 Pytree = Any
 

@@ -9,11 +9,16 @@ Drives the jitted train step over the synthetic pipeline with:
     :class:`DynamicOrchestrator` re-plans (template failover for failures,
     local reassignment for stragglers, threshold re-plan for bandwidth), and
     the trainer rebuilds its mesh/shardings and elastically reshards the
-    restored checkpoint onto the new layout,
-  * uneven heterogeneous batch shares consumed straight from the plan.
+    restored checkpoint onto the new layout.
 
-On CPU the mesh spans host devices; on a real cluster the same code runs
-under jax.distributed with the production mesh.
+The plan is carried into checkpoints but not yet executed: the mesh is the
+one passed in, or a data-parallel ``(n, 1)`` mesh over ``jax.devices()`` of
+this one process, and every device gets an equal batch share.
+
+Each (re)build compiles the step ahead of time at its first use, so
+``compile_s`` holds compile time apart from step time; every logged history
+entry carries ``step_s``, the step's wall time up to ``block_until_ready``;
+``event_log`` holds each handled event's stall (save through restore).
 """
 
 from __future__ import annotations
@@ -37,7 +42,8 @@ from repro.models.lm import LM
 from repro.optim.adamw import AdamWConfig
 from repro.parallel import sharding as shd
 from repro.parallel.axes import use_rules
-from repro.parallel.trainstep import init_train_state, make_train_step
+from repro.parallel.trainstep import (abstract_train_state, init_train_state,
+                                      make_train_step)
 
 Pytree = Any
 
@@ -103,6 +109,8 @@ class Trainer:
         self.saver = AsyncSaver()
         self.history: list[dict] = []
         self.replans = 0
+        self.compile_s: list[float] = []
+        self.event_log: list[dict] = []
         self._start_step = 0
         self._hist_mark = 0
         self._orch = None
@@ -116,12 +124,9 @@ class Trainer:
             self._engine = ReplanEngine(
                 desc, global_batch=cfg.global_batch, seq=cfg.seq_len,
                 cache=StrategyCache())
-            try:
-                # cold plan up front: warms the strategy cache + candidate
-                # portfolio so every later event takes a warm path
-                self._engine.plan(topo)
-            except RuntimeError:
-                pass
+            # cold plan up front: warms the strategy cache + candidate
+            # portfolio so every later event takes a warm path
+            self._engine.plan(topo)
             self._orch = DynamicOrchestrator(
                 model=desc, global_batch=cfg.global_batch, seq=cfg.seq_len,
                 engine=self._engine)
@@ -166,6 +171,7 @@ class Trainer:
         self._jit = jax.jit(wrapped, in_shardings=(self.state_sh, None),
                             out_shardings=(self.state_sh, None),
                             donate_argnums=(0,))
+        self._compiled = None
         a = self.cfg.arch
         self.data = SyntheticLM(DataConfig(
             vocab=a.vocab, seq_len=self.cfg.seq_len,
@@ -191,6 +197,7 @@ class Trainer:
     def _handle_event(self, step: int, ev: NetworkEvent,
                       state: Pytree) -> Pytree:
         assert self.topo is not None and self._orch is not None
+        t_event = time.perf_counter()
         self.saver.wait()
         ck = Path(self.cfg.ckpt_dir) / f"step_{step}"
         self.saver.submit(ck, state, step=step,
@@ -214,11 +221,14 @@ class Trainer:
         # mesh we rebuild shardings/jit against the new plan) and reshard
         # the checkpoint elastically onto the new layout.
         self._build(self.mesh)
-        like = init_train_state(self.model,
-                                jax.random.PRNGKey(self.cfg.seed))
         t0 = time.perf_counter()
-        restored, _ = restore(ck, like, shardings=self.state_sh)
+        restored, _ = restore(ck, abstract_train_state(self.model),
+                              shardings=self.state_sh)
+        jax.block_until_ready(restored)
         restore_s = time.perf_counter() - t0
+        self.event_log.append({"step": step, "kind": ev.kind,
+                               "restore_s": restore_s,
+                               "stall_s": time.perf_counter() - t_event})
         if self._engine is not None:
             # calibration hook: fold the measured checkpoint-restore path
             # into the reconfiguration cost model, so simulated switch
@@ -245,10 +255,18 @@ class Trainer:
                 state = self._handle_event(step, ev, state)
                 ev_i += 1
             batch = self._place(self.data.batch(step))
-            state, metrics = self._jit(state, batch)
+            if self._compiled is None:
+                t_compile = time.perf_counter()
+                self._compiled = self._jit.lower(state, batch).compile()
+                self.compile_s.append(time.perf_counter() - t_compile)
+            t_step = time.perf_counter()
+            state, metrics = self._compiled(state, batch)
             if step % cfg.log_every == 0 or step == cfg.steps - 1:
+                jax.block_until_ready((state, metrics))
+                step_s = time.perf_counter() - t_step
                 m = {k: float(v) for k, v in metrics.items()}
-                m.update(step=step, wall=time.perf_counter() - t0)
+                m.update(step=step, wall=time.perf_counter() - t0,
+                         step_s=step_s)
                 self.history.append(m)
                 tok_s = m["tokens"] * (step - start_step + 1) / m["wall"]
                 print(f"  step {step:4d} loss {m['loss']:.4f} "

@@ -170,3 +170,11 @@ def test_roofline_eq1_regimes():
     # memory-bound: tiny flops, huge traffic
     t_m = spec.roofline_time(1e6, 1e12)
     assert t_m == pytest.approx(1e12 / spec.hbm_bw)
+
+
+def test_device_kind_maps_to_profile_and_unknown_kind_is_an_error():
+    from repro.core import profile_for_device_kind
+    assert profile_for_device_kind("TPU v5 lite") == "TPUv5e"
+    assert "TPUv5e" in DEVICE_PROFILES
+    with pytest.raises(KeyError, match="no device profile"):
+        profile_for_device_kind("cpu")

@@ -108,6 +108,49 @@ def test_trainer_accepts_scenario_trace(tmp_path):
     assert np.isfinite(hist[-1]["loss"])
 
 
+def test_event_restore_through_abstract_template_is_bitwise(tmp_path):
+    """An event saves, replans, rebuilds and restores into the structure of
+    ``abstract_train_state`` (no second materialized state): the restored
+    bf16 params and fp32 moments equal the saved state bit for bit."""
+    cfg = get_config("qwen2_7b").reduced(n_layers=2, d_model=64, vocab=128,
+                                         d_ff=128, dtype="bfloat16")
+    tcfg = TrainerConfig(arch=cfg, steps=2, global_batch=4, seq_len=32,
+                         ckpt_dir=str(tmp_path), ckpt_every=0)
+    topo = hetero_cluster({"V100": 2}, gpus_per_node=2)
+    tr = Trainer(tcfg, topo=topo)
+    state, _ = tr.run()
+    saved = jax.device_get(state)
+    ev = NetworkEvent(0.0, "bandwidth", factor=0.5, selector="nvlink",
+                      mode="scale")
+    restored = tr._handle_event(2, ev, state)
+    flat_saved = jax.tree_util.tree_leaves_with_path(saved)
+    flat_restored = jax.tree_util.tree_leaves(restored)
+    assert len(flat_saved) == len(flat_restored)
+    for (path, a), b in zip(flat_saved, flat_restored):
+        b = np.asarray(jax.device_get(b))
+        assert b.dtype == a.dtype and b.shape == a.shape, path
+        assert b.tobytes() == np.asarray(a).tobytes(), path
+    assert jax.tree.leaves(restored)[0].sharding == \
+        jax.tree.leaves(tr.state_sh)[0]
+    assert [e["kind"] for e in tr.event_log] == ["bandwidth"]
+    assert tr.event_log[0]["stall_s"] >= tr.event_log[0]["restore_s"] > 0
+
+
+def test_trainer_reports_compile_apart_from_steps(tmp_path):
+    """Each (re)build compiles once, before its first step; logged steps
+    carry their own wall time."""
+    topo = hetero_cluster({"V100": 2}, gpus_per_node=2)
+    ev = NetworkEvent(0.0, "bandwidth", factor=0.5, selector="nvlink",
+                      mode="scale")
+    cfg = _tcfg(tmp_path, steps=4)
+    cfg.log_every = 1
+    tr = Trainer(cfg, topo=topo, events=[(2, ev)])
+    _, hist = tr.run()
+    assert len(tr.compile_s) == 2 and all(s > 0 for s in tr.compile_s)
+    assert [h["step"] for h in hist] == [0, 1, 2, 3]
+    assert all(h["step_s"] > 0 for h in hist)
+
+
 def test_plan_templates_failover_lookup():
     topo = hetero_cluster({"V100": 8}, gpus_per_node=8)
     desc = _tiny_cfg().to_model_desc()

@@ -29,6 +29,23 @@ def test_public_api_imports():
         assert cfg.shapes(), a
 
 
+def test_compile_cache_dir_is_env_or_fixed_repo_path(tmp_path, monkeypatch):
+    """Entry points keep the persistent compile cache where
+    JAX_COMPILATION_CACHE_DIR says, else at the fixed <root>/.jax_cache."""
+    from repro.launch.train import enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+        assert enable_compile_cache(tmp_path) == str(tmp_path / "env")
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        fixed = str(tmp_path.resolve() / ".jax_cache")
+        assert enable_compile_cache(tmp_path) == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 def test_plan_train_checkpoint_resume(tmp_path):
     """The full loop: auto-plan on an analytic cluster, train, checkpoint,
     build a NEW trainer, restore, and continue with matching loss."""
