@@ -640,63 +640,64 @@ def _mlstm_chunkwise(q, k, v, it, ft, state, *, chunk: int,
     q,k,v: (B,S,H,hd); it,ft: (B,S,H) f32 raw gates.  state = (C, n, m).
     Returns (y (B,S,H,hd) f32, new_state).
     """
-    Bb, S, H, hd = q.shape
-    n = S // chunk
-    f32 = jnp.float32
-    qc = q.reshape(Bb, n, chunk, H, hd).astype(f32)
-    kc = k.reshape(Bb, n, chunk, H, hd).astype(f32)
-    vc = v.reshape(Bb, n, chunk, H, hd).astype(f32)
-    ic = it.reshape(Bb, n, chunk, H)
-    logf = -jax.nn.softplus(-ft).reshape(Bb, n, chunk, H)
-    F = jnp.cumsum(logf, axis=2)                          # (B,n,c,H)
-    Gmax = jax.lax.cummax(ic - F, axis=2)                 # cummax(i_s - F_s)
+    with jax.named_scope("mlstm_cell"):
+        Bb, S, H, hd = q.shape
+        n = S // chunk
+        f32 = jnp.float32
+        qc = q.reshape(Bb, n, chunk, H, hd).astype(f32)
+        kc = k.reshape(Bb, n, chunk, H, hd).astype(f32)
+        vc = v.reshape(Bb, n, chunk, H, hd).astype(f32)
+        ic = it.reshape(Bb, n, chunk, H)
+        logf = -jax.nn.softplus(-ft).reshape(Bb, n, chunk, H)
+        F = jnp.cumsum(logf, axis=2)                          # (B,n,c,H)
+        Gmax = jax.lax.cummax(ic - F, axis=2)             # cummax(i_s - F_s)
 
-    C_in, n_in, m_in = state
+        C_in, n_in, m_in = state
 
-    def chunk_step(carry, inp):
-        C, nv, M = carry                      # (B,H,hd,hd),(B,H,hd),(B,H)
-        qt, kt, vt, i_t, F_t, Gm = inp        # k pre-scaled by 1/sqrt(hd)
-        # stabilizer per position: m_t = F_t + max(M_in, cummax_s(i_s-F_s))
-        m = F_t + jnp.maximum(M[:, None], Gm)             # (B,c,H)
-        # intra-chunk masked scores A_ts = (q_t.k_s) e^{F_t-F_s+i_s-m_t}
-        ratio = F_t[:, :, None] - F_t[:, None, :] + i_t[:, None, :] \
-            - m[:, :, None]                               # (B,t,s,H)
-        tri = (jnp.arange(chunk)[:, None] >= jnp.arange(chunk)[None, :])
-        ratio = jnp.where(tri[None, :, :, None], ratio, -1e30)
-        a = jnp.einsum("bthd,bshd->bhts", qt, kt)
-        A = a * jnp.moveaxis(jnp.exp(ratio), 3, 1)        # (B,H,t,s)
-        num_intra = jnp.einsum("bhts,bshd->bthd", A, vt)
-        den_intra = jnp.moveaxis(jnp.sum(A, axis=3), 1, 2)  # (B,t,H)
-        # cross-chunk contribution, decayed from the carried state
-        w_in = jnp.exp(F_t + M[:, None] - m)              # (B,c,H)
-        num_cross = jnp.einsum("bhkv,bthk->bthv", C, qt) * w_in[..., None]
-        den_cross = jnp.einsum("bhk,bthk->bth", nv, qt) * w_in
-        num = num_intra + num_cross
-        den = jnp.abs(den_intra + den_cross)
-        y = num / jnp.maximum(den, 1.0)[..., None]
-        # state update to chunk end
-        m_out = m[:, -1]                                  # (B,H)
-        Fc = F_t[:, -1]                                   # (B,H)
-        wS = jnp.exp(Fc + M - m_out)
-        wk = jnp.exp(Fc[:, None] - F_t + i_t - m_out[:, None])  # (B,c,H)
-        C_new = C * wS[..., None, None] + jnp.einsum(
-            "bshk,bshv,bsh->bhkv", kt, vt, wk)
-        n_new = nv * wS[..., None] + jnp.einsum("bshk,bsh->bhk", kt, wk)
-        return (C_new, n_new, m_out), y
+        def chunk_step(carry, inp):
+            C, nv, M = carry                      # (B,H,hd,hd),(B,H,hd),(B,H)
+            qt, kt, vt, i_t, F_t, Gm = inp        # k pre-scaled by 1/sqrt(hd)
+            # stabilizer per position: m_t = F_t + max(M_in, cummax_s(i_s-F_s))
+            m = F_t + jnp.maximum(M[:, None], Gm)             # (B,c,H)
+            # intra-chunk masked scores A_ts = (q_t.k_s) e^{F_t-F_s+i_s-m_t}
+            ratio = F_t[:, :, None] - F_t[:, None, :] + i_t[:, None, :] \
+                - m[:, :, None]                               # (B,t,s,H)
+            tri = (jnp.arange(chunk)[:, None] >= jnp.arange(chunk)[None, :])
+            ratio = jnp.where(tri[None, :, :, None], ratio, -1e30)
+            a = jnp.einsum("bthd,bshd->bhts", qt, kt)
+            A = a * jnp.moveaxis(jnp.exp(ratio), 3, 1)        # (B,H,t,s)
+            num_intra = jnp.einsum("bhts,bshd->bthd", A, vt)
+            den_intra = jnp.moveaxis(jnp.sum(A, axis=3), 1, 2)  # (B,t,H)
+            # cross-chunk contribution, decayed from the carried state
+            w_in = jnp.exp(F_t + M[:, None] - m)              # (B,c,H)
+            num_cross = jnp.einsum("bhkv,bthk->bthv", C, qt) * w_in[..., None]
+            den_cross = jnp.einsum("bhk,bthk->bth", nv, qt) * w_in
+            num = num_intra + num_cross
+            den = jnp.abs(den_intra + den_cross)
+            y = num / jnp.maximum(den, 1.0)[..., None]
+            # state update to chunk end
+            m_out = m[:, -1]                                  # (B,H)
+            Fc = F_t[:, -1]                                   # (B,H)
+            wS = jnp.exp(Fc + M - m_out)
+            wk = jnp.exp(Fc[:, None] - F_t + i_t - m_out[:, None])  # (B,c,H)
+            C_new = C * wS[..., None, None] + jnp.einsum(
+                "bshk,bshv,bsh->bhkv", kt, vt, wk)
+            n_new = nv * wS[..., None] + jnp.einsum("bshk,bsh->bhk", kt, wk)
+            return (C_new, n_new, m_out), y
 
-    xs = (jnp.moveaxis(qc, 1, 0), jnp.moveaxis(kc, 1, 0),
-          jnp.moveaxis(vc, 1, 0), jnp.moveaxis(ic, 1, 0),
-          jnp.moveaxis(F, 1, 0), jnp.moveaxis(Gmax, 1, 0))
-    if unroll and n <= 128:   # probe path; longer sequences would blow up
-        carry, ys = (C_in, n_in, m_in), []   # the unrolled HLO
-        for i in range(n):
-            carry, y = chunk_step(carry, jax.tree.map(lambda t: t[i], xs))
-            ys.append(y)
-        y = jnp.stack(ys, axis=1)
-    else:
-        carry, ys = lax.scan(chunk_step, (C_in, n_in, m_in), xs)
-        y = jnp.moveaxis(ys, 0, 1)
-    return y.reshape(Bb, S, H, hd), carry
+        xs = (jnp.moveaxis(qc, 1, 0), jnp.moveaxis(kc, 1, 0),
+              jnp.moveaxis(vc, 1, 0), jnp.moveaxis(ic, 1, 0),
+              jnp.moveaxis(F, 1, 0), jnp.moveaxis(Gmax, 1, 0))
+        if unroll and n <= 128:   # probe path; longer sequences would blow up
+            carry, ys = (C_in, n_in, m_in), []   # the unrolled HLO
+            for i in range(n):
+                carry, y = chunk_step(carry, jax.tree.map(lambda t: t[i], xs))
+                ys.append(y)
+            y = jnp.stack(ys, axis=1)
+        else:
+            carry, ys = lax.scan(chunk_step, (C_in, n_in, m_in), xs)
+            y = jnp.moveaxis(ys, 0, 1)
+        return y.reshape(Bb, S, H, hd), carry
 
 
 MLSTM_CHUNK = 64
@@ -800,7 +801,9 @@ def slstm_block(p, cfg, x, *, state=None, return_state=False):
         z32 = lambda: jnp.zeros((Bb, H, hd), jnp.float32)
         state = (z32(), z32(), jnp.zeros((Bb, H, hd), x.dtype),
                  jnp.full((Bb, H), -1e30, jnp.float32))
-    state, ys = chunked_time_scan(step, state, jnp.moveaxis(zifo, 1, 0))
+    with jax.named_scope("slstm_scan"):
+        state, ys = chunked_time_scan(step, state,
+                                      jnp.moveaxis(zifo, 1, 0))
     y = jnp.moveaxis(ys, 0, 1).reshape(Bb, S, d)
     y = rms_norm(y, p["gn"], cfg.norm_eps)
     up, gate = jnp.split(jnp.einsum("bsd,df->bsf", y, p["w_up"]), 2, -1)

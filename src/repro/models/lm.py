@@ -157,7 +157,8 @@ class LM:
         decode cache when ``return_cache`` (prefill path)."""
         cfg = self.cfg
         B, S = tokens.shape
-        x = L.embed(params["embed"], cfg, tokens)
+        with jax.named_scope("head"):
+            x = L.embed(params["embed"], cfg, tokens)
         positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
         memory = self._memory(params, audio_embed, vision_embed)
         shared = params.get("shared")
@@ -166,56 +167,59 @@ class LM:
             cache_out = []
             for p, kind in enumerate(cfg.pattern):
                 bp = cyc_params[f"pos{p}"]
-                if kind in ("attn", "cross_attn"):
-                    h = L.rms_norm(x, bp["attn"]["ln"], cfg.norm_eps)
-                    q, k, v = L._qkv(bp["attn"], cfg, h, positions)
-                    o = L.mha(q, k, v, causal=cfg.causal,
-                              q_chunk=cfg.attn_q_chunk, unroll=self.unroll)
-                    o = jnp.einsum("bshk,hkd->bsd", o, bp["attn"]["wo"])
-                    x = x + shard(o, "batch", "seq", "embed")
-                    if return_cache:
-                        cache_out.append({"k": k, "v": v})
-                    if kind == "cross_attn":
-                        x = L.cross_attn_block(bp["cross"], cfg, x, memory,
-                                               unroll=self.unroll)
-                    x = (L.moe_block(bp["moe"], cfg, x) if cfg.n_experts
-                         else L.ffn_block(bp["ffn"] if "ffn" in bp else
-                                          bp["moe"], cfg, x))
-                elif kind == "shared_attn":
-                    h = jnp.einsum("bsd,de->bse", x, bp["in_proj"])
-                    hn = L.rms_norm(h, shared["attn"]["ln"], cfg.norm_eps)
-                    q, k, v = L._qkv(shared["attn"], cfg, hn, positions)
-                    o = L.mha(q, k, v, causal=True, window=cfg.attn_window,
-                              q_chunk=cfg.attn_q_chunk, unroll=self.unroll)
-                    o = jnp.einsum("bshk,hkd->bsd", o, shared["attn"]["wo"])
-                    h = h + o
-                    h = L.ffn_block(shared["ffn"], cfg, h)
-                    x = x + h
-                    if return_cache:
-                        # ring-buffer layout: last W tokens at slots pos % W
-                        W = cfg.attn_window or S
-                        kc, vc = (t[:, -W:] if S >= W else
-                                  jnp.pad(t, ((0, 0), (0, W - S),
-                                              (0, 0), (0, 0)))
-                                  for t in (k, v))
-                        cache_out.append({"k": kc, "v": vc})
-                elif kind == "mamba":
-                    x, st, conv = L.mamba_block(bp["mamba"], cfg, x,
-                                                return_state=True,
-                                                unroll=self.unroll)
-                    if return_cache:
-                        cache_out.append({"ssm": st, "conv": conv})
-                elif kind == "mlstm":
-                    x, st = L.mlstm_block(bp["mlstm"], cfg, x,
-                                          return_state=True,
-                                          unroll=self.unroll)
-                    if return_cache:
-                        cache_out.append({"state": st})
-                elif kind == "slstm":
-                    x, st = L.slstm_block(bp["slstm"], cfg, x,
-                                          return_state=True)
-                    if return_cache:
-                        cache_out.append({"state": st})
+                with jax.named_scope(kind):
+                    if kind in ("attn", "cross_attn"):
+                        h = L.rms_norm(x, bp["attn"]["ln"], cfg.norm_eps)
+                        q, k, v = L._qkv(bp["attn"], cfg, h, positions)
+                        o = L.mha(q, k, v, causal=cfg.causal,
+                                  q_chunk=cfg.attn_q_chunk, unroll=self.unroll)
+                        o = jnp.einsum("bshk,hkd->bsd", o, bp["attn"]["wo"])
+                        x = x + shard(o, "batch", "seq", "embed")
+                        if return_cache:
+                            cache_out.append({"k": k, "v": v})
+                        if kind == "cross_attn":
+                            x = L.cross_attn_block(bp["cross"], cfg, x, memory,
+                                                   unroll=self.unroll)
+                        x = (L.moe_block(bp["moe"], cfg, x) if cfg.n_experts
+                             else L.ffn_block(bp["ffn"] if "ffn" in bp else
+                                              bp["moe"], cfg, x))
+                    elif kind == "shared_attn":
+                        h = jnp.einsum("bsd,de->bse", x, bp["in_proj"])
+                        hn = L.rms_norm(h, shared["attn"]["ln"], cfg.norm_eps)
+                        q, k, v = L._qkv(shared["attn"], cfg, hn, positions)
+                        o = L.mha(q, k, v, causal=True, window=cfg.attn_window,
+                                  q_chunk=cfg.attn_q_chunk, unroll=self.unroll)
+                        o = jnp.einsum("bshk,hkd->bsd", o,
+                                       shared["attn"]["wo"])
+                        h = h + o
+                        h = L.ffn_block(shared["ffn"], cfg, h)
+                        x = x + h
+                        if return_cache:
+                            # ring-buffer layout: last W tokens at slots
+                            # pos % W
+                            W = cfg.attn_window or S
+                            kc, vc = (t[:, -W:] if S >= W else
+                                      jnp.pad(t, ((0, 0), (0, W - S),
+                                                  (0, 0), (0, 0)))
+                                      for t in (k, v))
+                            cache_out.append({"k": kc, "v": vc})
+                    elif kind == "mamba":
+                        x, st, conv = L.mamba_block(bp["mamba"], cfg, x,
+                                                    return_state=True,
+                                                    unroll=self.unroll)
+                        if return_cache:
+                            cache_out.append({"ssm": st, "conv": conv})
+                    elif kind == "mlstm":
+                        x, st = L.mlstm_block(bp["mlstm"], cfg, x,
+                                              return_state=True,
+                                              unroll=self.unroll)
+                        if return_cache:
+                            cache_out.append({"state": st})
+                    elif kind == "slstm":
+                        x, st = L.slstm_block(bp["slstm"], cfg, x,
+                                              return_state=True)
+                        if return_cache:
+                            cache_out.append({"state": st})
             return x, tuple(cache_out)
 
         body = cycle
@@ -230,7 +234,8 @@ class LM:
         stacks = {f"pos{p}": params[f"pos{p}"]
                   for p in range(len(cfg.pattern))}
         x, caches = self._scan(body, x, stacks)
-        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        with jax.named_scope("head"):
+            x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
         if return_cache:
             return x, caches
         return x
@@ -242,7 +247,8 @@ class LM:
     def loss(self, params: Pytree, tokens: jax.Array, labels: jax.Array,
              *, remat: str = "none", **mods) -> jax.Array:
         x = self.forward(params, tokens, remat=remat, **mods)
-        return L.xent_loss(x, params["embed"]["tok"], labels, self.cfg)
+        with jax.named_scope("head"):
+            return L.xent_loss(x, params["embed"]["tok"], labels, self.cfg)
 
     def prefill(self, params: Pytree, tokens: jax.Array, **mods):
         """Serving prefill: returns (last-token logits, decode cache)."""

@@ -27,17 +27,18 @@ from __future__ import annotations
 
 import atexit
 import os
+import time
 
-from .export import (METRICS_KEY, chrome_trace, write_jsonl,  # noqa: F401
+from .export import (METRICS_KEY, chrome_trace,  # noqa: F401
                      write_metrics, write_trace)
 from .metrics import (DEFAULT_BUCKETS, Counter, Histogram,  # noqa: F401
                       MetricsRegistry)
 from .tracer import NULL_HANDLE, Span, Tracer, _NullHandle  # noqa: F401
 
 __all__ = [
-    "Obs", "NULL_OBS", "resolve_obs", "default_obs",
+    "Obs", "NULL_OBS", "resolve_obs", "default_obs", "Timed",
     "Tracer", "Span", "MetricsRegistry", "Counter", "Histogram",
-    "chrome_trace", "write_trace", "write_jsonl", "write_metrics",
+    "chrome_trace", "write_trace", "write_metrics",
     "METRICS_KEY", "DEFAULT_BUCKETS",
 ]
 
@@ -79,6 +80,10 @@ class Obs:
             return NULL_HANDLE
         return self.tracer.span(name, **attrs)
 
+    def timed(self, name: str, **attrs) -> "Timed":
+        """A span whose times the caller keeps as well (:class:`Timed`)."""
+        return Timed(self, name, attrs)
+
     def inc(self, name: str, n: int = 1) -> None:
         """Increment counter ``name`` by ``n`` (no-op when disabled)."""
         if self.enabled and n:
@@ -113,6 +118,34 @@ class Obs:
         if not self.enabled:
             return None
         return self.tracer.span_dicts(), self.metrics.snapshot()
+
+
+class Timed:
+    """Context manager around a region the caller times for its own records:
+    ``t0``, ``t1`` and ``seconds`` after it closes.  The region is read once:
+    with the bundle enabled these are the span's own ``perf_counter`` reads,
+    and otherwise two reads of its own beside the shared no-op handle."""
+
+    __slots__ = ("_handle", "t0", "t1")
+
+    def __init__(self, obs: Obs, name: str, attrs: dict):
+        self._handle = obs.span(name, **attrs)
+        self.t0 = self.t1 = None
+
+    def __enter__(self) -> "Timed":
+        span = getattr(self._handle.__enter__(), "span", None)
+        self.t0 = span.t0 if span is not None else time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._handle.__exit__(*exc)
+        span = getattr(self._handle, "span", None)
+        self.t1 = span.t1 if span is not None else time.perf_counter()
+
+    @property
+    def seconds(self) -> float:
+        """``t1 - t0``."""
+        return self.t1 - self.t0
 
 
 NULL_OBS = Obs(enabled=False)

@@ -1,4 +1,4 @@
-"""Exporters: Chrome-trace/Perfetto JSON, JSONL event log, metrics files.
+"""Exporters: Chrome-trace/Perfetto JSON and metrics files.
 
 The Chrome trace format (``{"traceEvents": [...]}``) loads directly in
 Perfetto (https://ui.perfetto.dev) and ``chrome://tracing``: each span
@@ -75,21 +75,6 @@ def write_trace(obs: "Obs", path: str | Path) -> Path:
     if p.parent != Path("."):
         p.parent.mkdir(parents=True, exist_ok=True)
     p.write_text(json.dumps(chrome_trace(obs), sort_keys=True))
-    return p
-
-
-def write_jsonl(obs: "Obs", path: str | Path) -> Path:
-    """Write the structured event log: one JSON object per line — every
-    finished span (``{"kind": "span", ...}``) followed by one final
-    ``{"kind": "metrics", ...}`` snapshot record."""
-    p = Path(path)
-    if p.parent != Path("."):
-        p.parent.mkdir(parents=True, exist_ok=True)
-    lines = [json.dumps({"kind": "span", **s.to_dict()})
-             for s in obs.tracer.spans]
-    lines.append(json.dumps({"kind": "metrics",
-                             "metrics": obs.metrics.snapshot()}))
-    p.write_text("\n".join(lines) + "\n")
     return p
 
 

@@ -11,12 +11,19 @@ dicts back with their result payload, and the parent **re-parents** them
 under the span that enqueued the work (:meth:`Tracer.adopt`) — worker span
 ids are remapped into the parent's id space, worker pids are preserved so
 exporters can draw one lane per worker process.
+
+Where JAX is already imported, every span is mirrored onto the profiler's
+clock: it enters a ``jax.profiler.TraceAnnotation`` of its name when it
+opens and leaves it when it closes, so a ``jax.profiler`` trace shows it on
+its host plane beside the device's operations.  The module never imports
+JAX itself, so the planner stays stdlib-only.
 """
 
 from __future__ import annotations
 
 import itertools
 import os
+import sys
 import threading
 import time
 from dataclasses import dataclass, field
@@ -42,10 +49,21 @@ class Span:
         return (self.t1 - self.t0) if self.t1 is not None else 0.0
 
     def to_dict(self) -> dict:
-        """Plain-JSON form (the worker shipping + JSONL event format)."""
+        """Plain-JSON form (the worker shipping format)."""
         return {"name": self.name, "t0": self.t0, "t1": self.t1,
                 "span_id": self.span_id, "parent_id": self.parent_id,
                 "pid": self.pid, "tid": self.tid, "attrs": self.attrs}
+
+
+def _profiler_annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation(name)``, or None where JAX
+    has not been imported."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    annotation = jax.profiler.TraceAnnotation(name)
+    annotation.__enter__()
+    return annotation
 
 
 class _SpanHandle:
@@ -53,11 +71,12 @@ class _SpanHandle:
     span so callers can attach attributes discovered mid-region
     (``handle.set(simulated=12)``)."""
 
-    __slots__ = ("_tracer", "span")
+    __slots__ = ("_tracer", "span", "_annotation")
 
-    def __init__(self, tracer: "Tracer", span: Span):
+    def __init__(self, tracer: "Tracer", span: Span, annotation=None):
         self._tracer = tracer
         self.span = span
+        self._annotation = annotation
 
     @property
     def span_id(self) -> int:
@@ -73,6 +92,9 @@ class _SpanHandle:
 
     def __exit__(self, *exc) -> None:
         self._tracer._close(self.span)
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
 
 
 class _NullHandle:
@@ -138,7 +160,7 @@ class Tracer:
                   parent_id=parent, pid=os.getpid(),
                   tid=threading.get_ident(), attrs=dict(attrs))
         stack.append(sp)
-        return _SpanHandle(self, sp)
+        return _SpanHandle(self, sp, _profiler_annotation(name))
 
     def _close(self, span: Span) -> None:
         span.t1 = time.perf_counter()

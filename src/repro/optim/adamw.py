@@ -69,31 +69,32 @@ def global_norm(tree: Pytree) -> jax.Array:
 def adamw_update(params: Pytree, grads: Pytree, state: OptState,
                  cfg: AdamWConfig) -> tuple[Pytree, OptState, dict]:
     """One AdamW step.  Returns (new_params, new_state, metrics)."""
-    gnorm = global_norm(grads)
-    scale = jnp.minimum(1.0, cfg.clip_norm / jnp.maximum(gnorm, 1e-12))
-    step = state.step + 1
-    lr = cosine_lr(cfg, step)
-    b1c = 1.0 - cfg.b1 ** step.astype(jnp.float32)
-    b2c = 1.0 - cfg.b2 ** step.astype(jnp.float32)
+    with jax.named_scope("adamw"):
+        gnorm = global_norm(grads)
+        scale = jnp.minimum(1.0, cfg.clip_norm / jnp.maximum(gnorm, 1e-12))
+        step = state.step + 1
+        lr = cosine_lr(cfg, step)
+        b1c = 1.0 - cfg.b1 ** step.astype(jnp.float32)
+        b2c = 1.0 - cfg.b2 ** step.astype(jnp.float32)
 
-    def upd(p, g, m, v):
-        g = g.astype(jnp.float32) * scale
-        m = cfg.b1 * m + (1 - cfg.b1) * g
-        v = cfg.b2 * v + (1 - cfg.b2) * jnp.square(g)
-        mhat = m / b1c
-        vhat = v / b2c
-        delta = mhat / (jnp.sqrt(vhat) + cfg.eps) \
-            + cfg.weight_decay * p.astype(jnp.float32)
-        return (p.astype(jnp.float32) - lr * delta).astype(p.dtype), m, v
+        def upd(p, g, m, v):
+            g = g.astype(jnp.float32) * scale
+            m = cfg.b1 * m + (1 - cfg.b1) * g
+            v = cfg.b2 * v + (1 - cfg.b2) * jnp.square(g)
+            mhat = m / b1c
+            vhat = v / b2c
+            delta = mhat / (jnp.sqrt(vhat) + cfg.eps) \
+                + cfg.weight_decay * p.astype(jnp.float32)
+            return (p.astype(jnp.float32) - lr * delta).astype(p.dtype), m, v
 
-    flat_p, tdef = jax.tree.flatten(params)
-    flat_g = jax.tree.leaves(grads)
-    flat_m = jax.tree.leaves(state.m)
-    flat_v = jax.tree.leaves(state.v)
-    out = [upd(p, g, m, v) for p, g, m, v in
-           zip(flat_p, flat_g, flat_m, flat_v)]
-    new_p = jax.tree.unflatten(tdef, [o[0] for o in out])
-    new_m = jax.tree.unflatten(tdef, [o[1] for o in out])
-    new_v = jax.tree.unflatten(tdef, [o[2] for o in out])
-    metrics = {"grad_norm": gnorm, "lr": lr}
-    return new_p, OptState(new_m, new_v, step), metrics
+        flat_p, tdef = jax.tree.flatten(params)
+        flat_g = jax.tree.leaves(grads)
+        flat_m = jax.tree.leaves(state.m)
+        flat_v = jax.tree.leaves(state.v)
+        out = [upd(p, g, m, v) for p, g, m, v in
+               zip(flat_p, flat_g, flat_m, flat_v)]
+        new_p = jax.tree.unflatten(tdef, [o[0] for o in out])
+        new_m = jax.tree.unflatten(tdef, [o[1] for o in out])
+        new_v = jax.tree.unflatten(tdef, [o[2] for o in out])
+        metrics = {"grad_norm": gnorm, "lr": lr}
+        return new_p, OptState(new_m, new_v, step), metrics

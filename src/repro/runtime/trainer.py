@@ -19,6 +19,15 @@ Each (re)build compiles the step ahead of time at its first use, so
 ``compile_s`` holds compile time apart from step time; every logged history
 entry carries ``step_s``, the step's wall time up to ``block_until_ready``;
 ``event_log`` holds each handled event's stall (save through restore).
+
+With ``repro.obs`` enabled (``obs=`` or ``REPRO_TRACE``) the loop records
+spans, mirrored onto the profiler's clock: ``trainer.batch`` (make and
+place the batch), ``trainer.compile`` (attribute ``step``),
+``trainer.dispatch`` (the compiled call), ``trainer.sync`` (a log step's
+``block_until_ready``), ``trainer.ckpt`` (a periodic save's submit), and
+per event ``trainer.event`` (attribute ``kind``) around
+``trainer.event.save``, ``.replan``, ``.rebuild`` and ``.restore``.  The
+timings above are those spans' own clock reads.
 """
 
 from __future__ import annotations
@@ -39,6 +48,7 @@ from repro.checkpoint.store import AsyncSaver, latest_step, restore
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.models.config import ArchConfig
 from repro.models.lm import LM
+from repro.obs import Obs, resolve_obs
 from repro.optim.adamw import AdamWConfig
 from repro.parallel import sharding as shd
 from repro.parallel.axes import use_rules
@@ -70,8 +80,10 @@ class Trainer:
                  plan: ParallelPlan | None = None,
                  topo: ClusterTopology | None = None,
                  events: Sequence[tuple[int, NetworkEvent]] = (),
-                 scenario: "str | object | None" = None):
+                 scenario: "str | object | None" = None,
+                 obs: Obs | None = None):
         self.cfg = cfg
+        self.obs = resolve_obs(obs)
         self.model = LM(cfg.arch)
         self.plan = plan
         self.topo = topo
@@ -197,38 +209,44 @@ class Trainer:
     def _handle_event(self, step: int, ev: NetworkEvent,
                       state: Pytree) -> Pytree:
         assert self.topo is not None and self._orch is not None
-        t_event = time.perf_counter()
-        self.saver.wait()
-        ck = Path(self.cfg.ckpt_dir) / f"step_{step}"
-        self.saver.submit(ck, state, step=step,
-                          plan_json=self.plan.to_json() if self.plan else "")
-        self.saver.wait()
-        self.topo.apply_event(ev)
-        if self._engine is not None and len(self.history) > self._hist_mark:
-            # remaining-horizon budget for the engine's switch-cost
-            # hysteresis: steps left x the measured mean step wall time.
-            # Only entries logged by *this* run() invocation qualify: their
-            # wall is measured from this run's t0 and covers the steps since
-            # start_step (a previous run's entries would mix timebases)
-            m = self.history[-1]
-            done = max(m["step"] - self._start_step + 1, 1)
-            self._engine.switch_horizon_s = \
-                (self.cfg.steps - step) * m["wall"] / done
-        old_plan = self.plan or ParallelPlan()
-        self.plan = self._orch.adapt(old_plan, self.topo, ev)
-        self.replans += 1
-        # rebuild (the mesh shape may change on a real cluster; on the host
-        # mesh we rebuild shardings/jit against the new plan) and reshard
-        # the checkpoint elastically onto the new layout.
-        self._build(self.mesh)
-        t0 = time.perf_counter()
-        restored, _ = restore(ck, abstract_train_state(self.model),
-                              shardings=self.state_sh)
-        jax.block_until_ready(restored)
-        restore_s = time.perf_counter() - t0
+        obs = self.obs
+        with obs.timed("trainer.event", kind=ev.kind) as stall:
+            with obs.span("trainer.event.save"):
+                self.saver.wait()
+                ck = Path(self.cfg.ckpt_dir) / f"step_{step}"
+                self.saver.submit(ck, state, step=step,
+                                  plan_json=self.plan.to_json()
+                                  if self.plan else "")
+                self.saver.wait()
+            with obs.span("trainer.event.replan"):
+                self.topo.apply_event(ev)
+                if (self._engine is not None
+                        and len(self.history) > self._hist_mark):
+                    # remaining-horizon budget for the engine's switch-cost
+                    # hysteresis: steps left x the measured mean step wall
+                    # time.  Only entries logged by *this* run() invocation
+                    # qualify: their wall is measured from this run's t0
+                    # and covers the steps since start_step (a previous
+                    # run's entries would mix timebases)
+                    m = self.history[-1]
+                    done = max(m["step"] - self._start_step + 1, 1)
+                    self._engine.switch_horizon_s = \
+                        (self.cfg.steps - step) * m["wall"] / done
+                old_plan = self.plan or ParallelPlan()
+                self.plan = self._orch.adapt(old_plan, self.topo, ev)
+                self.replans += 1
+            # rebuild (the mesh shape may change on a real cluster; on the
+            # host mesh we rebuild shardings/jit against the new plan) and
+            # reshard the checkpoint elastically onto the new layout.
+            with obs.span("trainer.event.rebuild"):
+                self._build(self.mesh)
+            with obs.timed("trainer.event.restore") as rs:
+                restored, _ = restore(ck, abstract_train_state(self.model),
+                                      shardings=self.state_sh)
+                jax.block_until_ready(restored)
         self.event_log.append({"step": step, "kind": ev.kind,
-                               "restore_s": restore_s,
-                               "stall_s": time.perf_counter() - t_event})
+                               "restore_s": rs.seconds,
+                               "stall_s": stall.seconds})
         if self._engine is not None:
             # calibration hook: fold the measured checkpoint-restore path
             # into the reconfiguration cost model, so simulated switch
@@ -236,7 +254,7 @@ class Trainer:
             nbytes = sum(
                 getattr(leaf, "nbytes", 0)
                 for leaf in jax.tree_util.tree_leaves(restored))
-            self._engine.reconfig.calibrate_io(restore_s, float(nbytes))
+            self._engine.reconfig.calibrate_io(rs.seconds, float(nbytes))
         return restored
 
     # -- main loop -------------------------------------------------------------
@@ -244,6 +262,7 @@ class Trainer:
     def run(self, state: Pytree | None = None,
             start_step: int = 0) -> tuple[Pytree, list[dict]]:
         cfg = self.cfg
+        obs = self.obs
         state = state if state is not None else self.init_state()
         self._start_step = start_step
         self._hist_mark = len(self.history)
@@ -254,28 +273,30 @@ class Trainer:
                 _, ev = self.events[ev_i]
                 state = self._handle_event(step, ev, state)
                 ev_i += 1
-            batch = self._place(self.data.batch(step))
+            with obs.span("trainer.batch"):
+                batch = self._place(self.data.batch(step))
             if self._compiled is None:
-                t_compile = time.perf_counter()
-                self._compiled = self._jit.lower(state, batch).compile()
-                self.compile_s.append(time.perf_counter() - t_compile)
-            t_step = time.perf_counter()
-            state, metrics = self._compiled(state, batch)
+                with obs.timed("trainer.compile", step=step) as compiling:
+                    self._compiled = self._jit.lower(state, batch).compile()
+                self.compile_s.append(compiling.seconds)
+            with obs.timed("trainer.dispatch") as dispatch:
+                state, metrics = self._compiled(state, batch)
             if step % cfg.log_every == 0 or step == cfg.steps - 1:
-                jax.block_until_ready((state, metrics))
-                step_s = time.perf_counter() - t_step
+                with obs.timed("trainer.sync") as sync:
+                    jax.block_until_ready((state, metrics))
                 m = {k: float(v) for k, v in metrics.items()}
-                m.update(step=step, wall=time.perf_counter() - t0,
-                         step_s=step_s)
+                m.update(step=step, wall=sync.t1 - t0,
+                         step_s=sync.t1 - dispatch.t0)
                 self.history.append(m)
                 tok_s = m["tokens"] * (step - start_step + 1) / m["wall"]
                 print(f"  step {step:4d} loss {m['loss']:.4f} "
                       f"gnorm {m['grad_norm']:.2f} lr {m['lr']:.2e} "
                       f"tok/s {tok_s:,.0f}", flush=True)
             if cfg.ckpt_every and step and step % cfg.ckpt_every == 0:
-                self.saver.submit(Path(cfg.ckpt_dir) / f"step_{step}",
-                                  state, step=step,
-                                  plan_json=self.plan.to_json()
-                                  if self.plan else "")
+                with obs.span("trainer.ckpt"):
+                    self.saver.submit(Path(cfg.ckpt_dir) / f"step_{step}",
+                                      state, step=step,
+                                      plan_json=self.plan.to_json()
+                                      if self.plan else "")
         self.saver.wait()
         return state, self.history

@@ -335,3 +335,48 @@ def test_chrome_trace_lane_attr_groups_onto_named_rows():
     plain = [e for e in doc["traceEvents"]
              if e["ph"] == "X" and e["name"] == "plain"]
     assert plain[0]["tid"] not in lane_tids
+
+
+# ---------------------------------------------------------------------------
+# The profiler's clock, and regions timed once
+# ---------------------------------------------------------------------------
+
+
+def test_enabled_span_shows_on_the_profilers_host_plane(tmp_path):
+    """With JAX imported, an enabled span is also a TraceAnnotation: a
+    ``jax.profiler`` trace holds it on a host plane, around the work."""
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    obs = Obs()
+    x = jnp.ones((64, 64))
+    jax.profiler.start_trace(str(tmp_path))
+    with obs.span("trainer.batch"):
+        (x @ x).block_until_ready()
+    jax.profiler.stop_trace()
+    NULL_OBS.span("never.recorded").__exit__(None, None, None)
+    found = sorted(tmp_path.rglob("*.xplane.pb"))
+    assert found
+    host = [(e.name, e.duration_ns) for plane in
+            ProfileData.from_file(str(found[-1])).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+    mine = [d for n, d in host if n == "trainer.batch"]
+    assert len(mine) == 1 and mine[0] > 0
+    assert not any(n == "never.recorded" for n, _ in host)
+    assert [s.name for s in obs.tracer.spans] == ["trainer.batch"]
+
+
+def test_timed_region_is_read_once():
+    """Enabled, a timed region's seconds are its span's own clock reads;
+    disabled, it keeps its own reads beside the shared no-op handle."""
+    obs = Obs()
+    with obs.timed("trainer.compile", step=3) as t:
+        pass
+    (span,) = obs.tracer.spans
+    assert (t.t0, t.t1) == (span.t0, span.t1)
+    assert t.seconds == span.duration and span.attrs == {"step": 3}
+    with NULL_OBS.timed("trainer.compile", step=3) as off:
+        pass
+    assert off._handle is NULL_HANDLE and off.seconds >= 0.0

@@ -160,3 +160,59 @@ def test_plan_templates_failover_lookup():
     assert tpl.plan_for(7).world <= 7
     with pytest.raises(KeyError):
         tpl.plan_for(0)
+
+
+def test_trainer_with_null_obs_records_no_span(tmp_path, monkeypatch):
+    """Tracing off, every span of the loop is the shared no-op handle: no
+    span is opened and nothing reaches the profiler."""
+    from repro.obs import NULL_OBS
+    from repro.obs import tracer
+
+    opened = []
+    monkeypatch.setattr(tracer.Tracer, "span",
+                        lambda self, name, **kw: opened.append(name))
+    monkeypatch.setattr(tracer, "_profiler_annotation",
+                        lambda name: opened.append(name))
+    cfg = _tcfg(tmp_path, steps=6)
+    cfg.log_every = 1
+    tr = Trainer(cfg, obs=NULL_OBS)
+    _, hist = tr.run()
+    assert tr.obs is NULL_OBS and opened == []
+    assert len(tr.compile_s) == 1 and all(h["step_s"] > 0 for h in hist)
+
+
+def test_trainer_spans_are_its_timings(tmp_path):
+    """Tracing on, the loop and the event handler record their spans, and
+    compile_s, step_s, restore_s and stall_s are those spans' own reads."""
+    from repro.obs import Obs
+
+    topo = hetero_cluster({"V100": 2}, gpus_per_node=2)
+    ev = NetworkEvent(0.0, "bandwidth", factor=0.5, selector="nvlink",
+                      mode="scale")
+    cfg = _tcfg(tmp_path, steps=6)
+    cfg.log_every = 2
+    obs = Obs()
+    tr = Trainer(cfg, topo=topo, events=[(3, ev)], obs=obs)
+    _, hist = tr.run()
+    spans = obs.tracer.spans
+    by = {}
+    for s in spans:
+        by.setdefault(s.name, []).append(s)
+    assert len(by["trainer.batch"]) == len(by["trainer.dispatch"]) == 6
+    assert [s.attrs["step"] for s in by["trainer.compile"]] == [0, 3]
+    assert tr.compile_s == [s.duration for s in by["trainer.compile"]]
+    assert len(by["trainer.ckpt"]) == 1             # step 5, ckpt_every 5
+    syncs = by["trainer.sync"]
+    assert len(syncs) == len(hist) == 4             # steps 0, 2, 4, 5
+    for h, sync in zip(hist, syncs):
+        last = max((d for d in by["trainer.dispatch"] if d.t1 <= sync.t0),
+                   key=lambda d: d.t0)
+        assert h["step_s"] == sync.t1 - last.t0
+    (event,) = by["trainer.event"]
+    assert event.attrs == {"kind": "bandwidth"}
+    for part in ("save", "replan", "rebuild", "restore"):
+        (child,) = by[f"trainer.event.{part}"]
+        assert child.parent_id == event.span_id
+    assert tr.event_log[0]["stall_s"] == event.duration
+    assert tr.event_log[0]["restore_s"] == \
+        by["trainer.event.restore"][0].duration
