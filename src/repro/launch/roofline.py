@@ -28,6 +28,7 @@ from dataclasses import asdict, dataclass, field
 import jax
 
 from repro.models.config import ArchConfig, ShapeSpec
+from repro.models.layers import mlstm_chunk
 from repro.models.lm import LM
 
 # TPU v5e constants (assignment).
@@ -316,12 +317,11 @@ def recurrent_correction(cfg: ArchConfig, shape: ShapeSpec,
         elif kind == "mlstm":
             H = cfg.n_heads
             hd = 2 * d // H
-            chunked = S % 64 == 0 and S > 64
-            if chunked and S // 64 <= 128:
+            c = mlstm_chunk(S, hd)
+            if c and S // c <= 128:
                 continue      # probes unroll the chunk loop: counted exactly
-            if chunked:
+            if c:
                 # chunkwise analytic: intra matmuls + per-chunk state io
-                c = 64
                 flops += n_occ * B_loc * H * (4 * S * c * hd + 8 * (S // c)
                                               * hd * hd)
                 byts += n_occ * (S // c) * B_loc * 2 * H * hd * hd * 4

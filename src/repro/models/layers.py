@@ -637,16 +637,18 @@ def _mlstm_chunkwise(q, k, v, it, ft, state, *, chunk: int,
     e^{F_t + M_in - m_t} (S_in q_t).  State materializes once per chunk and
     the MXU does the rest — same shape as the chunkwise SSD (Mamba2) path.
 
-    q,k,v: (B,S,H,hd); it,ft: (B,S,H) f32 raw gates.  state = (C, n, m).
-    Returns (y (B,S,H,hd) f32, new_state).
+    q,k,v: (B,S,H,hd) in their own dtype (products with the f32 state and
+    intra-chunk terms are f32, and so are q.k and k v^T);
+    it,ft: (B,S,H) f32 raw gates.  state = (C, n, m), f32.
+    Returns (y (B,S,H,hd) in v's dtype, new_state).
     """
     with jax.named_scope("mlstm_cell"):
         Bb, S, H, hd = q.shape
         n = S // chunk
         f32 = jnp.float32
-        qc = q.reshape(Bb, n, chunk, H, hd).astype(f32)
-        kc = k.reshape(Bb, n, chunk, H, hd).astype(f32)
-        vc = v.reshape(Bb, n, chunk, H, hd).astype(f32)
+        qc = q.reshape(Bb, n, chunk, H, hd)
+        kc = k.reshape(Bb, n, chunk, H, hd)
+        vc = v.reshape(Bb, n, chunk, H, hd)
         ic = it.reshape(Bb, n, chunk, H)
         logf = -jax.nn.softplus(-ft).reshape(Bb, n, chunk, H)
         F = jnp.cumsum(logf, axis=2)                          # (B,n,c,H)
@@ -664,7 +666,8 @@ def _mlstm_chunkwise(q, k, v, it, ft, state, *, chunk: int,
                 - m[:, :, None]                               # (B,t,s,H)
             tri = (jnp.arange(chunk)[:, None] >= jnp.arange(chunk)[None, :])
             ratio = jnp.where(tri[None, :, :, None], ratio, -1e30)
-            a = jnp.einsum("bthd,bshd->bhts", qt, kt)
+            a = jnp.einsum("bthd,bshd->bhts", qt, kt,
+                           preferred_element_type=f32)
             A = a * jnp.moveaxis(jnp.exp(ratio), 3, 1)        # (B,H,t,s)
             num_intra = jnp.einsum("bhts,bshd->bthd", A, vt)
             den_intra = jnp.moveaxis(jnp.sum(A, axis=3), 1, 2)  # (B,t,H)
@@ -681,9 +684,9 @@ def _mlstm_chunkwise(q, k, v, it, ft, state, *, chunk: int,
             wS = jnp.exp(Fc + M - m_out)
             wk = jnp.exp(Fc[:, None] - F_t + i_t - m_out[:, None])  # (B,c,H)
             C_new = C * wS[..., None, None] + jnp.einsum(
-                "bshk,bshv,bsh->bhkv", kt, vt, wk)
+                "bshk,bshv,bsh->bhkv", kt, vt, wk, preferred_element_type=f32)
             n_new = nv * wS[..., None] + jnp.einsum("bshk,bsh->bhk", kt, wk)
-            return (C_new, n_new, m_out), y
+            return (C_new, n_new, m_out), y.astype(vt.dtype)
 
         xs = (jnp.moveaxis(qc, 1, 0), jnp.moveaxis(kc, 1, 0),
               jnp.moveaxis(vc, 1, 0), jnp.moveaxis(ic, 1, 0),
@@ -700,7 +703,22 @@ def _mlstm_chunkwise(q, k, v, it, ft, state, *, chunk: int,
         return y.reshape(Bb, S, H, hd), carry
 
 
-MLSTM_CHUNK = 64
+def mlstm_chunk(S: int, hd: int) -> int | None:
+    """Chunk length of :func:`_mlstm_chunkwise` for ``S`` tokens of head
+    dim ``hd``, or None where the sequential scan runs (decode, odd lengths).
+
+    Each chunk reads and writes the (hd, hd) f32 state once, against c·hd
+    values per head of q, k and v, so the chunk grows with the head: the
+    smallest power of two >= hd/2 within [64, 256], halved until it
+    divides S with S > c."""
+    c = 64
+    while c < hd / 2 and c < 256:
+        c *= 2
+    while c >= 64:
+        if S % c == 0 and S > c:
+            return c
+        c //= 2
+    return None
 
 
 def mlstm_block(p, cfg, x, *, state=None, return_state=False,
@@ -742,9 +760,10 @@ def mlstm_block(p, cfg, x, *, state=None, return_state=False,
         state = (jnp.zeros((Bb, H, hd, hd), jnp.float32),
                  jnp.zeros((Bb, H, hd), jnp.float32),
                  jnp.full((Bb, H), -1e30, jnp.float32))
-    if S % MLSTM_CHUNK == 0 and S > MLSTM_CHUNK:
+    chunk = mlstm_chunk(S, hd)
+    if chunk:
         ys, state = _mlstm_chunkwise(q, k, v, it, ft, state,
-                                     chunk=MLSTM_CHUNK, unroll=unroll)
+                                     chunk=chunk, unroll=unroll)
         y = ys.astype(x.dtype).reshape(Bb, S, e)
     else:
         xs = tuple(jnp.moveaxis(t, 1, 0) for t in (q, k, v, it, ft))

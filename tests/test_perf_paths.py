@@ -155,40 +155,128 @@ def test_pad_heads_exactness():
                                atol=2e-5, rtol=2e-5)
 
 
-def test_chunkwise_mlstm_matches_sequential():
+def _mlstm_inputs(B, S, H, hd, seed=7, dtype=jnp.float32):
     import math
-    key = jax.random.PRNGKey(7)
-    B, S, H, hd, chunk = 2, 192, 3, 16, 64
-    ks = jax.random.split(key, 5)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     q = jax.random.normal(ks[0], (B, S, H, hd))
     k = jax.random.normal(ks[1], (B, S, H, hd)) / math.sqrt(hd)
     v = jax.random.normal(ks[2], (B, S, H, hd))
     it = (jax.random.normal(ks[3], (B, S, H)) * 2).astype(jnp.float32)
     ft = (jax.random.normal(ks[4], (B, S, H)) * 2 + 1).astype(jnp.float32)
+    return (q.astype(dtype), k.astype(dtype), v.astype(dtype), it, ft)
 
-    C = jnp.zeros((B, H, hd, hd))
-    n = jnp.zeros((B, H, hd))
-    m = jnp.full((B, H), -1e30)
-    ys = []
-    for t in range(S):
-        qt, kt, vt = q[:, t], k[:, t], v[:, t]
-        logf = -jax.nn.softplus(-ft[:, t])
-        m_new = jnp.maximum(logf + m, it[:, t])
+
+def _mlstm_state0(B, H, hd):
+    return (jnp.zeros((B, H, hd, hd)), jnp.zeros((B, H, hd)),
+            jnp.full((B, H), -1e30))
+
+
+@jax.jit
+def _mlstm_sequential(q, k, v, it, ft):
+    """The mLSTM recurrence one token at a time, in float32: y and the
+    final (C, n, m)."""
+    B, S, H, hd = q.shape
+
+    def step(carry, inp):
+        C, n, m = carry
+        qt, kt, vt, i_t, f_t = inp
+        logf = -jax.nn.softplus(-f_t)
+        m_new = jnp.maximum(logf + m, i_t)
         fg = jnp.exp(logf + m - m_new)[..., None]
-        ig = jnp.exp(it[:, t] - m_new)[..., None]
+        ig = jnp.exp(i_t - m_new)[..., None]
         C = C * fg[..., None] + ig[..., None] * (kt[..., :, None]
                                                  * vt[..., None, :])
         n = n * fg + ig * kt
-        num = jnp.einsum("bhkv,bhk->bhv", C, qt)
-        den = jnp.abs(jnp.einsum("bhk,bhk->bh", n, qt))
-        ys.append(num / jnp.maximum(den, 1.0)[..., None])
-        m = m_new
-    y_ref = jnp.stack(ys, 1)
+        num = jnp.einsum("bhkv,bhk->bhv", C, qt,
+                         precision=jax.lax.Precision.HIGHEST)
+        den = jnp.abs(jnp.einsum("bhk,bhk->bh", n, qt,
+                                 precision=jax.lax.Precision.HIGHEST))
+        return (C, n, m_new), num / jnp.maximum(den, 1.0)[..., None]
 
-    state0 = (jnp.zeros((B, H, hd, hd)), jnp.zeros((B, H, hd)),
-              jnp.full((B, H), -1e30))
-    y_chk, (C_c, n_c, m_c) = L._mlstm_chunkwise(q, k, v, it, ft, state0,
-                                                chunk=chunk)
+    xs = tuple(jnp.moveaxis(t.astype(jnp.float32), 1, 0)
+               for t in (q, k, v, it, ft))
+    state, ys = jax.lax.scan(step, _mlstm_state0(B, H, hd), xs)
+    return jnp.moveaxis(ys, 0, 1), state
+
+
+@pytest.mark.parametrize("B,S,H,hd,chunk", [
+    (2, 192, 3, 16, 64),
+    (1, 512, 2, 128, 64),
+    (1, 512, 2, 128, 128),
+    (1, 512, 2, 128, 256),
+])
+def test_chunkwise_mlstm_matches_sequential(B, S, H, hd, chunk):
+    q, k, v, it, ft = _mlstm_inputs(B, S, H, hd)
+    y_ref, (C, n, m) = _mlstm_sequential(q, k, v, it, ft)
+    y_chk, (C_c, n_c, m_c) = L._mlstm_chunkwise(
+        q, k, v, it, ft, _mlstm_state0(B, H, hd), chunk=chunk)
     np.testing.assert_allclose(y_ref, y_chk, atol=3e-4, rtol=3e-3)
     np.testing.assert_allclose(m, m_c, atol=1e-5)
     np.testing.assert_allclose(C, C_c, atol=3e-4, rtol=3e-3)
+    np.testing.assert_allclose(n, n_c, atol=3e-4, rtol=3e-3)
+
+
+@pytest.mark.parametrize("chunk", [64, None])
+def test_chunkwise_mlstm_gradients_match_sequential(chunk):
+    """Gradients through the chunkwise cell, at 64 and at the chunk that
+    ``mlstm_chunk`` picks for the shape (256), against the sequential
+    recurrence's, for q, k, v and both gates."""
+    B, S, H, hd = 1, 512, 1, 384
+    chunk = chunk or L.mlstm_chunk(S, hd)
+    args = _mlstm_inputs(B, S, H, hd, seed=3)
+    w = jax.random.normal(jax.random.PRNGKey(11), (B, S, H, hd))
+
+    def loss(f):
+        def go(*a):
+            y, (C, n, m) = f(*a)
+            return jnp.sum(y * w) + jnp.sum(C) * 1e-2 + jnp.sum(n)
+        return go
+
+    chunked = lambda *a: L._mlstm_chunkwise(*a, _mlstm_state0(B, H, hd),
+                                             chunk=chunk)
+    g_chk = jax.jit(jax.grad(loss(chunked), argnums=range(5)))(*args)
+    g_ref = jax.jit(jax.grad(loss(_mlstm_sequential),
+                             argnums=range(5)))(*args)
+    for name, gc, gr in zip("q k v it ft".split(), g_chk, g_ref):
+        scale = float(jnp.max(jnp.abs(gr)))
+        np.testing.assert_allclose(gc, gr, atol=1e-4 * scale, rtol=1e-3,
+                                   err_msg=name)
+
+
+def test_chunkwise_mlstm_keeps_bf16_inputs():
+    """bf16 q, k, v give the cell's result on the same values in float32,
+    rounded once to bf16 on the way out; the state stays float32."""
+    B, S, H, hd = 1, 512, 2, 384
+    chunk = L.mlstm_chunk(S, hd)
+    q, k, v, it, ft = _mlstm_inputs(B, S, H, hd, dtype=jnp.bfloat16)
+    up = [t.astype(jnp.float32) for t in (q, k, v)]
+    y16, st16 = L._mlstm_chunkwise(q, k, v, it, ft, _mlstm_state0(B, H, hd),
+                                   chunk=chunk)
+    y32, st32 = L._mlstm_chunkwise(*up, it, ft, _mlstm_state0(B, H, hd),
+                                   chunk=chunk)
+    assert y16.dtype == jnp.bfloat16 and y32.dtype == jnp.float32
+    assert all(t.dtype == jnp.float32 for t in st16)
+    # one rounding to bf16 (8 bits of mantissa) of y
+    np.testing.assert_allclose(y16.astype(jnp.float32), y32,
+                               atol=1e-6, rtol=2 ** -8)
+    for a, b in zip(st16, st32):
+        np.testing.assert_allclose(a, b, atol=1e-6, rtol=1e-5)
+
+
+@pytest.mark.parametrize("S,hd,chunk", [
+    (4096, 384, 256),     # xLSTM-125M: d 768, e 2d, 4 heads
+    (4096, 128, 64),      # small heads keep 64
+    (192, 16, 64),
+    (4096, 192, 128),
+    (4096, 1024, 256),    # clamped
+    (192, 384, 64),       # halved until it divides S
+    (128, 384, 64),       # S > c
+    (64, 384, None),      # one chunk: the sequential scan
+    (1, 384, None),       # decode
+    (100, 128, None),     # no chunk of 64 or more divides S
+])
+def test_mlstm_chunk_rule(S, hd, chunk):
+    c = L.mlstm_chunk(S, hd)
+    assert c == chunk
+    if c is not None:
+        assert S % c == 0 and S > c
