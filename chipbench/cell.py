@@ -41,10 +41,15 @@ CACHE_HIT = "/jax/compilation_cache/cache_hits"
 
 @dataclass
 class RunRecord:
-    """What the window left for the readers in ``chipbench/metrics``."""
+    """What the window left for the readers in ``chipbench/metrics``, and
+    what they count from: the configuration's file as run, the traffic's
+    sequence length and the chip's peaks (``peaks.json``)."""
     chips: int
     flops_per_token: float
     peak_flops: float
+    hbm_bytes_per_s: float
+    config: dict
+    seq_len: int
     chunks: list = field(default_factory=list)
     trace: dict | None = None
     window_events: list = field(default_factory=list)
@@ -259,8 +264,10 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
 
     # -- traced part and window ---------------------------------------------
     rec = RunRecord(chips=cell.chips, peak_flops=peak["bf16_flops_per_s"],
+                    hbm_bytes_per_s=peak["hbm_bytes_per_s"],
                     flops_per_token=flops.train_per_token(
-                        cell.config["arch"], cell.traffic["seq_len"]))
+                        cell.config["arch"], cell.traffic["seq_len"]),
+                    config=cell.config, seq_len=cell.traffic["seq_len"])
     n_compiled = compiles.compiled
     n_events_before = len(trainer.event_log)
     chunks = plan_chunks(CHECK_STEPS, event_steps,

@@ -1,10 +1,14 @@
-"""Token embedding and the tied output projection with cross-entropy.
+"""Token embedding and the output projection with cross-entropy.
 
-    x_0 = E[tokens];   loss = sum_t (logsumexp(x_t E^T) - (x_t E^T)[label_t])
+    x_0 = E[tokens];   loss = sum_t (logsumexp(x_t W^T) - (x_t W^T)[label_t])
 
-The sum runs over the rows given; the caller divides by the batch's
-token count.  Logits are formed a block of positions at a time so that a
-(rows, positions, vocab) array never sits in memory whole.
+W is the embedding E itself where the configuration ties them
+(``tie_embeddings`` true or not given, as ``ArchConfig`` defaults), and
+otherwise a head of its own, ``embed.head`` of shape (vocab, d_model): the
+program must lay an untied head out at ``params["embed"]["head"]``.  The
+sum runs over the rows given; the caller divides by the batch's token
+count.  Logits are formed a block of positions at a time so that a (rows,
+positions, vocab) array never sits in memory whole.
 """
 
 from __future__ import annotations
@@ -19,8 +23,10 @@ BLOCK = 512
 
 def param_shapes(cfg):
     d = cfg["d_model"]
-    return {"embed": {"tok": ((cfg["vocab"], d), 0.02)},
-            "final_norm": ((d,), "ones")}
+    emb = {"tok": ((cfg["vocab"], d), 0.02)}
+    if not cfg.get("tie_embeddings", True):
+        emb["head"] = ((cfg["vocab"], d), 0.02)
+    return {"embed": emb, "final_norm": ((d,), "ones")}
 
 
 def embed(p, tokens):
@@ -29,10 +35,11 @@ def embed(p, tokens):
 
 def xent_sum(p, x, labels, mm):
     S = x.shape[1]
+    w = p.get("head", p["tok"])
 
     @jax.checkpoint
     def part(x_blk, lab_blk):
-        logits = mm("bsd,vd->bsv", x_blk, p["tok"])
+        logits = mm("bsd,vd->bsv", x_blk, w)
         lse = jax.nn.logsumexp(logits, axis=-1)
         pick = jnp.take_along_axis(logits, lab_blk[..., None], axis=-1)[..., 0]
         return jnp.sum(lse - pick)

@@ -2,10 +2,27 @@
 file per block kind, its mean cross-entropy, its gradients and the
 optimizer's first steps, in float32 (or in the control's precision).
 
-A block kind ``k`` is the module ``reference/k.py`` with a function
-``block(p, x, cfg, mm, shared)``.  The loss and gradients of a batch are
-taken a block of rows at a time and summed, which is exact, so that the
-reference fits in the chip's memory at the timed sizes.
+A block kind ``k`` is the module ``reference/k.py``.  It gives:
+
+    KEY                 the name the layer's weights sit under in its
+                        ``pos<j>`` slot, or None for the slot itself;
+    param_shapes(cfg)   {name: (shape, init)} of one layer (``weights.py``
+                        stacks them over the pattern's cycles);
+    shared_shapes(cfg)  optional: weights shared by all its layers, passed
+                        to every block as ``shared``;
+    INPUTS              optional: more inputs the block takes, each passed
+                        as a keyword: ``"x0"``, the embedding's output as
+                        ``embed`` gives it (float32, (rows, positions,
+                        d_model)); ``"cycle"``, the index of the pattern's
+                        cycle the layer is in (a traced int32 scalar);
+    block(p, x, cfg, mm, shared, **inputs)  the layer's output.
+
+A block kind that declares no ``INPUTS`` is called as
+``block(p, x, cfg=, mm=, shared=)`` alone, and the scan carries the cycle
+index only where some kind of the pattern declares it.  The loss and
+gradients of a batch are taken a block of rows at a time and summed, which
+is exact, so that the reference fits in the chip's memory at the timed
+sizes.
 """
 
 from __future__ import annotations
@@ -22,8 +39,7 @@ from .embed_xent import embed, xent_sum
 
 
 def kind_module(kind: str):
-    """``reference/<kind>.py``: ``KEY`` (the name the layer's weights sit
-    under in its slot, None for none), ``param_shapes(cfg)`` and ``block``."""
+    """``reference/<kind>.py``, as the module docstring describes it."""
     return importlib.import_module(f"{__package__}.{kind}")
 
 
@@ -36,14 +52,21 @@ def loss_sum(params, tokens, labels, *, cfg, mm):
     mods = [kind_module(kind) for kind in pattern]
     stacks = [params[f"pos{j}"][m.KEY] if m.KEY else params[f"pos{j}"]
               for j, m in enumerate(mods)]
+    wants = [getattr(m, "INPUTS", ()) for m in mods]
+    x0 = embed(params["embed"], tokens)
+    counted = any("cycle" in w for w in wants)
 
-    def cycle(x, layer):
-        for m, p in zip(mods, layer):
+    def cycle(carry, layer):
+        x, c = carry if counted else (carry, None)
+        given = {"x0": x0, "cycle": c}
+        for m, p, w in zip(mods, layer, wants):
             x = jax.checkpoint(partial(m.block, cfg=cfg, mm=mm))(
-                p, x, shared=shared)
-        return x, None
+                p, x, shared=shared, **{k: given[k] for k in w})
+        return ((x, c + 1) if counted else x), None
 
-    x, _ = jax.lax.scan(cycle, embed(params["embed"], tokens), stacks)
+    init = (x0, jnp.zeros((), jnp.int32)) if counted else x0
+    out, _ = jax.lax.scan(cycle, init, stacks)
+    x = out[0] if counted else out
     x = rms_norm(x, params["final_norm"], cfg["norm_eps"])
     return xent_sum(params["embed"], x, labels, mm)
 

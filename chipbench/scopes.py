@@ -2,10 +2,11 @@
 
 Each operation on a device (the ``XLA Ops`` line of each
 ``/device:TPU:<n>`` plane) has a layer: the innermost of the program's
-``jax.named_scope`` names (``SCOPES``) on its ``op_name``, read through
-the wrappers that differentiation and recomputation put around a scope, so
-that the forward pass, its recomputation and the backward pass all count
-under it.  An operation under none of them is ``UNSCOPED``.  Self times
+``jax.named_scope`` names on its ``op_name`` (``SCOPES``, and those that
+the configuration's file lists under ``"scopes"``: ``scope_set``), read
+through the wrappers that differentiation and recomputation put around a
+scope, so that the forward pass, its recomputation and the backward pass
+all count under it.  An operation under none of them is ``UNSCOPED``.  Self times
 are taken as in ``chipbench/trace.py`` (a loop keeps what its body leaves,
 and counts under the scope it was built in), inside the host span named
 ``window``, averaged over the devices: the scopes sum to its ``busy_s``.
@@ -30,7 +31,8 @@ from collections import defaultdict
 from chipbench import trace as tracing
 
 # the program's jax.named_scope names: one per block kind, and the parts
-# inside a block or around it that the per-layer metrics read
+# inside a block or around it that the per-layer metrics read; a
+# configuration adds its kernels' scopes in its own file
 SCOPES = ("attn", "cross_attn", "shared_attn", "mamba", "mlstm", "slstm",
           "mlstm_cell", "slstm_scan", "head", "adamw")
 UNSCOPED = "unscoped"
@@ -41,8 +43,14 @@ HLO_PROTO_STAT = b"Hlo Proto"
 PROGRAM_ID_STAT = b"program_id"
 
 
-def scope_of(op_name: str) -> str:
-    """The innermost of ``SCOPES`` on an ``op_name`` path, or
+def scope_set(config: dict) -> tuple:
+    """``SCOPES`` and the scopes a configuration (its file, as run) lists
+    under ``"scopes"``: the named scopes of its own kernels."""
+    return SCOPES + tuple(config.get("scopes", ()))
+
+
+def scope_of(op_name: str, scopes: tuple = SCOPES) -> str:
+    """The innermost of ``scopes`` on an ``op_name`` path, or
     ``UNSCOPED``: ``jit(f)/transpose(jvp())/while/body/mlstm/mlstm_cell/
     while/body/exp`` -> ``mlstm_cell``, ``jit(f)/transpose(jvp(head))/dot``
     -> ``head``."""
@@ -50,7 +58,7 @@ def scope_of(op_name: str) -> str:
     for part in op_name.split("/"):
         while part.startswith(WRAPPERS) and part.endswith(")"):
             part = part[part.index("(") + 1:-1]
-        if part in SCOPES:
+        if part in scopes:
             found = part
     return found
 
@@ -193,8 +201,9 @@ def scope_times(spans, ops, *, window: str = WINDOW) -> dict:
     return {k: v / n for k, v in out.items()}
 
 
-def read(path, *, window: str = WINDOW) -> dict:
-    """Self seconds per scope (``scope_times``) of a trace."""
+def read(path, *, window: str = WINDOW, scopes: tuple = SCOPES) -> dict:
+    """Self seconds per scope of ``scopes`` (``scope_times``) of a
+    trace."""
     from jax.profiler import ProfileData
     xplane = tracing.find_xplane(path)
     names = op_names(xplane.read_bytes())
@@ -202,12 +211,12 @@ def read(path, *, window: str = WINDOW) -> dict:
     spans, ops = [], {}
     for plane in pd.planes:
         if plane.name.startswith(tracing.DEVICE_PREFIX):
-            scopes = {k: scope_of(v)
-                      for k, v in names.get(plane.name, {}).items()}
+            found = {k: scope_of(v, scopes)
+                     for k, v in names.get(plane.name, {}).items()}
             evs = ops.setdefault(plane.name, [])
             for line in plane.lines:
                 if line.name == tracing.OPS_LINE:
-                    evs.extend((scopes.get(e.name, UNSCOPED), e.start_ns,
+                    evs.extend((found.get(e.name, UNSCOPED), e.start_ns,
                                 e.start_ns + e.duration_ns)
                                for e in line.events)
         elif plane.name.startswith("/host:"):
@@ -218,19 +227,21 @@ def read(path, *, window: str = WINDOW) -> dict:
 
 
 @functools.lru_cache(maxsize=1)
-def _read_once(path: str, mtime_ns: int) -> dict:
-    return read(path)
+def _read_once(path: str, mtime_ns: int, scopes: tuple) -> dict:
+    return read(path, scopes=scopes)
 
 
 def per_step_ms(run, scope: str):
     """Device self milliseconds per traced step of ``scope`` in a finished
     run (:class:`chipbench.cell.RunRecord`), read from the trace the run
-    left; None in an untraced run or where no operation of ``scope`` ran."""
+    left, over the scope set of the run's configuration (``scope_set``);
+    None in an untraced run or where no operation of ``scope`` ran."""
     if run.trace is None:
         return None
     from chipbench.cell import RUN_DIR
     xplane = tracing.find_xplane(RUN_DIR / "trace")
-    seconds = _read_once(str(xplane), xplane.stat().st_mtime_ns)
+    seconds = _read_once(str(xplane), xplane.stat().st_mtime_ns,
+                         scope_set(run.config))
     steps = sum(c["steps"] for c in run.chunks if c["traced"])
     if scope not in seconds or not steps:
         return None

@@ -51,7 +51,8 @@ def cpu_run(tmp_path, monkeypatch):
     from jax.experimental.compilation_cache import compilation_cache as cc
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jc"))
     monkeypatch.setattr(C, "RUN_DIR", tmp_path / "run")
-    monkeypatch.setattr(C, "peaks", lambda kind: {"bf16_flops_per_s": 1e12})
+    monkeypatch.setattr(C, "peaks", lambda kind: {"bf16_flops_per_s": 1e12,
+                                                  "hbm_bytes_per_s": 1e11})
     before = (jax.config.jax_compilation_cache_dir,
               jax.config.jax_persistent_cache_min_compile_time_secs)
     cc.reset_cache()

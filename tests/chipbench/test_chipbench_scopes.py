@@ -59,6 +59,22 @@ def test_scope_of_reads_through_wrappers(op_name, scope):
     assert scopes.scope_of(op_name) == scope
 
 
+SSD = ("jit(f)/transpose(jvp())/while/body/closed_call/checkpoint/"
+       "rematted_computation/mamba/mamba2_ssd/while/body/dot_general")
+
+
+@pytest.mark.parametrize("config, scope", [
+    ({"scopes": ["mamba2_ssd"]}, "mamba2_ssd"),
+    ({}, "mamba"),
+    ({"scopes": ["other_kernel"]}, "mamba"),
+])
+def test_declared_scope_is_filed_under_itself(config, scope):
+    """A kernel scope that the configuration's file declares is a layer of
+    its own; undeclared, its ops stay with the enclosing block."""
+    assert scopes.scope_of(SSD, scopes.scope_set(config)) == scope
+    assert scopes.scope_set({}) == scopes.SCOPES
+
+
 def test_op_names_of_the_recorded_trace():
     """The op_names of the recorded trace's device ops, from the program's
     HLO that the profiler keeps beside them: the two matmul fusions of
@@ -92,10 +108,17 @@ def _msg(*fields):
     return out
 
 
-def _plane(name, stats, events):
+def _plane(name, stats, events, lines=()):
     """An XPlane: stat metadata {id: name}, event metadata as (id, name,
-    [XStat])."""
+    [XStat]), and lines as (name, [(metadata id, start ms, length ms)])
+    (XLine: name 2, timestamp_ns 3, events 4; XEvent: metadata_id 1,
+    offset_ps 2, duration_ps 3)."""
+    ps = 10 ** 9
     return _msg((2, name),
+                *[(3, _msg((2, ln), (3, 1000),
+                           *[(4, _msg((1, i), (2, a * ps), (3, d * ps)))
+                             for i, a, d in evs]))
+                  for ln, evs in lines],
                 *[(5, _msg((1, i), (2, _msg((1, i), (2, n)))))
                   for i, n in stats.items()],
                 *[(4, _msg((1, i), (2, _msg((1, i), (2, n),
@@ -103,16 +126,21 @@ def _plane(name, stats, events):
                   for i, n, sts in events])
 
 
+def _hlo(*instructions):
+    """An HloProto of one computation: (instruction name, op_name)."""
+    return _msg((1, _msg((3, _msg(*[
+        (2, _msg((1, ins), (7, _msg((1, "mul"), (2, op)))))
+        for ins, op in instructions])))))
+
+
 def test_op_names_follow_the_program_id():
     """Two programs with an instruction of the same name: each device op
     takes the op_name of the program it ran in."""
-    def hlo(instruction, op_name):
-        return _msg((1, _msg((3, _msg((2, _msg(
-            (1, instruction), (7, _msg((1, "mul"), (2, op_name))))))))))
-
     meta = _plane("/host:metadata", {1: "Hlo Proto"}, [
-        (7, "jit_f(7)", [_msg((1, 1), (6, hlo("fusion.1", "jit(f)/head")))]),
-        (9, "jit_g(9)", [_msg((1, 1), (6, hlo("fusion.1", "jit(g)/adamw")))]),
+        (7, "jit_f(7)", [_msg((1, 1), (6, _hlo(("fusion.1",
+                                                 "jit(f)/head"))))]),
+        (9, "jit_g(9)", [_msg((1, 1), (6, _hlo(("fusion.1",
+                                                 "jit(g)/adamw"))))]),
     ])
     f7, f9, c = ("%fusion.1 = f32[] fusion()", "%fusion.1 = f32[2] fusion()",
                  "%copy.2 = f32[] copy()")
@@ -122,6 +150,33 @@ def test_op_names_follow_the_program_id():
     names = scopes.op_names(_msg((1, meta), (1, dev)))
     assert names == {"/device:TPU:0": {f7: "jit(f)/head",
                                        f9: "jit(g)/adamw"}}
+
+
+def test_declared_scope_in_a_trace(tmp_path):
+    """A written trace of one Mamba layer whose SSD kernel has a named
+    scope: declared, the kernel's self time is its own and the block's is
+    the rest; undeclared, the block holds both, as before.  The scopes
+    sum to busy either way."""
+    fusions = [("fusion.1", "jit(f)/jvp(mamba)/in_proj/dot_general"),
+               ("fusion.2", SSD), ("fusion.3", "jit(f)/jvp()/while")]
+    meta = _plane("/host:metadata", {1: "Hlo Proto"}, [
+        (7, "jit_f(7)", [_msg((1, 1), (6, _hlo(*fusions)))])])
+    host = _plane("/host:CPU", {}, [(1, scopes.WINDOW, [])],
+                  [("python", [(1, 0, 20)])])
+    dev = _plane("/device:TPU:0", {3: "program_id"},
+                 [(i, f"%{n} = f32[] fusion()", [_msg((1, 3), (3, 7))])
+                  for i, (n, _) in enumerate(fusions, start=1)],
+                 [(trace.OPS_LINE, [(1, 1, 4), (2, 5, 6), (3, 12, 2)])])
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(_msg((1, meta), (1, host), (1, dev)))
+    plain = scopes.read(path)
+    declared = scopes.read(path, scopes=scopes.scope_set(
+        {"scopes": ["mamba2_ssd"]}))
+    assert plain == pytest.approx({"mamba": 0.010, "unscoped": 0.002})
+    assert declared == pytest.approx({"mamba": 0.004, "mamba2_ssd": 0.006,
+                                      "unscoped": 0.002})
+    busy = trace.reduce(*trace.read(path), window=scopes.WINDOW)["busy_s"]
+    assert sum(declared.values()) == pytest.approx(busy)
 
 
 def test_recorded_trace_per_scope():
@@ -167,7 +222,8 @@ def test_scope_readers(tmp_path, monkeypatch):
     the scope ran."""
     from chipbench import cell, metrics
 
-    run = cell.RunRecord(chips=1, flops_per_token=1.0, peak_flops=1.0)
+    run = cell.RunRecord(chips=1, flops_per_token=1.0, peak_flops=1.0,
+                         hbm_bytes_per_s=1.0, config={}, seq_len=1)
     run.chunks = [{"kind": "steady", "steps": 2, "traced": True},
                   {"kind": "steady", "steps": 7, "traced": False}]
     assert metrics.read("unscoped_ms", run) is None
