@@ -19,7 +19,16 @@ import jax.numpy as jnp
 
 from repro.core.opgraph import ModelDesc
 
-BlockKind = Literal["attn", "mamba", "mlstm", "slstm", "shared_attn"]
+BlockKind = Literal["attn", "mamba", "mlstm", "slstm", "shared_attn",
+                    "mamba2", "zamba2_hybrid"]
+
+# Zamba2's published constants (Zyphra/Zamba2-7B config.json): B and C of
+# the Mamba2 mixers in 2 groups (``mamba_ngroups``), 2 shared transformer
+# blocks used in turn (``num_mem_blocks``), a LoRA adapter of rank 128 on
+# the shared MLP at each hybrid layer (``adapter_rank``).
+ZAMBA2_SSM_GROUPS = 2
+ZAMBA2_SHARED_SETS = 2
+ZAMBA2_ADAPTER_RANK = 128
 
 
 @dataclass(frozen=True)
@@ -40,6 +49,20 @@ LONG_500K = ShapeSpec("long_500k", 524288, 1, "decode")
 ALL_SHAPES: tuple[ShapeSpec, ...] = (TRAIN_4K, PREFILL_32K, DECODE_32K,
                                      LONG_500K)
 SHAPES_BY_NAME = {s.name: s for s in ALL_SHAPES}
+
+
+@dataclass(frozen=True)
+class Zamba2Share:
+    """What one chip holds of each Zamba2 layer (:attr:`ArchConfig.zamba2`)
+    when tensor parallelism splits the layer over ``ranks`` chips: the
+    Mamba2 mixer's heads and groups of B and C, the shared MLP's columns,
+    and the shared weight sets its hybrid layers use."""
+
+    ranks: int
+    ssm_heads: int
+    ssm_groups: int
+    mlp: int
+    sets: int
 
 
 @dataclass(frozen=True)
@@ -126,11 +149,38 @@ class ArchConfig:
     def jnp_dtype(self):
         return jnp.dtype(self.dtype)
 
+    @property
+    def zamba2(self) -> Zamba2Share:
+        """The chip's share of a Zamba2 layer (kinds ``mamba2`` and
+        ``zamba2_hybrid``).  The fields state Zamba2's published widths,
+        and ``n_heads`` the attention heads this chip holds.  The shared
+        attention's heads span the 2d-wide concat(x, embedding) whole
+        (Zamba2-7B: 32 heads of 224 = 2 x 3584), so they give the ranks a
+        layer is split over, and each rank holds as much of every part:
+        1/ranks of the Mamba2 heads (``ssm_expand * d_model /
+        ssm_head_dim``) and of its groups, and of the MLP's ``d_ff``
+        columns.  A pipeline stage holds the shared sets its hybrid layers
+        use: its j-th uses set j mod 2."""
+        ranks, rest = divmod(2 * self.d_model, self.n_heads * self.hd)
+        heads = self.ssm_expand * self.d_model // self.ssm_head_dim
+        if rest or ZAMBA2_SSM_GROUPS % ranks or heads % ranks \
+                or self.d_ff % ranks:
+            raise ValueError(
+                f"{self.name}: {self.n_heads} heads of {self.hd} are no "
+                f"share of a Zamba2 layer of width 2 x {self.d_model}")
+        return Zamba2Share(
+            ranks=ranks, ssm_heads=heads // ranks,
+            ssm_groups=ZAMBA2_SSM_GROUPS // ranks, mlp=self.d_ff // ranks,
+            sets=min(ZAMBA2_SHARED_SETS, self.n_cycles))
+
     # -- planner bridge -------------------------------------------------------
 
     def to_model_desc(self) -> ModelDesc:
-        pattern = tuple("mamba" if b == "mamba" else
-                        ("mlstm" if b in ("mlstm", "slstm") else "attn")
+        # Zamba2's hybrid layer is priced as its Mamba2 layer alone: the
+        # shared block over concat(x, embedding) is not priced yet
+        # (ROADMAP 2.2)
+        pattern = tuple("mamba" if b in ("mamba", "mamba2", "zamba2_hybrid")
+                        else ("mlstm" if b in ("mlstm", "slstm") else "attn")
                         for b in self.pattern) if self.block_pattern else ()
         return ModelDesc(
             name=self.name, n_layers=self.n_layers, d_model=self.d_model,

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.models.config import ZAMBA2_ADAPTER_RANK
 from repro.parallel.axes import shard
 
 Axes = tuple[str | None, ...]
@@ -167,15 +168,17 @@ def mha(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
         q_positions: jax.Array | None = None,
         kv_positions: jax.Array | None = None,
         window: int = 0, q_chunk: int = 1024,
-        softcap: float = 0.0, unroll: bool = False) -> jax.Array:
+        softcap: float = 0.0, unroll: bool = False,
+        scale: float | None = None) -> jax.Array:
     """Grouped-query attention, chunked over queries (bounded memory).
 
     q: (B, Sq, KV, G, hd);  k, v: (B, Skv, KV, hd).  Returns (B, Sq, KV*G, hd).
-    Masks: causal by position, optional sliding ``window``.
+    Masks: causal by position, optional sliding ``window``.  Scores are
+    scaled by ``scale``, 1/sqrt(hd) unless given.
     """
     B, Sq, KV, G, hd = q.shape
     Skv = k.shape[1]
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
     if q_positions is None:
         q_positions = jnp.arange(Sq)[None, :] + (Skv - Sq)
         q_positions = jnp.broadcast_to(q_positions, (B, Sq))
@@ -438,43 +441,66 @@ def chunked_time_scan(step, carry, xs, *, chunk: int = TIME_SCAN_CHUNK):
 # ---------------------------------------------------------------------------
 
 
-def mamba_defs(cfg) -> dict:
+def mamba_defs(cfg, *, heads: int | None = None, groups: int = 0) -> dict:
+    """Weights of a Mamba2 block.
+
+    ``groups`` 0 is the repo's first Mamba block: ``ssm_expand * d_model /
+    ssm_head_dim`` heads, one B and one C for all of them, the causal
+    convolution over x alone and without bias, one norm over all channels.
+    ``groups`` G >= 1 is Mamba2 as published: ``heads`` heads, B and C per
+    group (head h reads group h // (heads / G)), the convolution, with
+    bias, over x, B and C, and the gated norm per group of e / G channels.
+    """
     d = cfg.d_model
-    e = cfg.ssm_expand * d
-    nh = e // cfg.ssm_head_dim
+    nh = heads or cfg.ssm_expand * d // cfg.ssm_head_dim
+    e = nh * cfg.ssm_head_dim
     N, W = cfg.ssm_state, cfg.ssm_conv_width
-    return {
+    bc = groups * N if groups else N
+    defs = {
         "ln": ParamDef((d,), ("embed",), init="ones"),
         "w_z": ParamDef((d, e), ("fsdp", "mlp")),
         "w_x": ParamDef((d, e), ("fsdp", "mlp")),
-        "w_B": ParamDef((d, N), ("fsdp", "state")),
-        "w_C": ParamDef((d, N), ("fsdp", "state")),
+        "w_B": ParamDef((d, bc), ("fsdp", "state")),
+        "w_C": ParamDef((d, bc), ("fsdp", "state")),
         "w_dt": ParamDef((d, nh), ("fsdp", "heads")),
-        "conv_w": ParamDef((W, e), ("conv", "mlp"), scale=0.5),
+        "conv_w": ParamDef((W, e + 2 * bc if groups else e), ("conv", "mlp"),
+                           scale=0.5),
         "A_log": ParamDef((nh,), ("heads",), init="zeros"),
         "D": ParamDef((nh,), ("heads",), init="ones"),
         "dt_bias": ParamDef((nh,), ("heads",), init="zeros"),
         "gn": ParamDef((e,), ("mlp",), init="ones"),
         "w_out": ParamDef((e, d), ("mlp", "fsdp")),
     }
+    if groups:
+        defs["conv_b"] = ParamDef((e + 2 * bc,), ("mlp",), init="zeros")
+    return defs
+
+
+def _per_head(t: jax.Array, nh: int) -> jax.Array:
+    """B or C as (B, S, nh, N): (B, S, N) is one group for every head;
+    of (B, S, G, N), head h reads group h // (nh / G)."""
+    t = t[:, :, None] if t.ndim == 3 else t
+    return jnp.repeat(t, nh // t.shape[2], axis=2)
 
 
 def _mamba_scan_seq(x, B_in, C_in, dt, A_log, D, hd, *, h0=None):
     """Sequential SSD recurrence (reference / decode path).
 
     h_t = exp(A*dt_t) h_{t-1} + dt_t * x_t B_t^T ;  y_t = h_t C_t + D x_t
+    B_in, C_in: (B,S,N) for all heads, or (B,S,G,N) per group.
     Returns (y (B,S,nh,hd), h_final (B,nh,hd,N)).
     """
     Bb, S, nh, _ = x.shape
+    B_in, C_in = _per_head(B_in, nh), _per_head(C_in, nh)
     N = B_in.shape[-1]
     A = -jnp.exp(A_log.astype(jnp.float32))              # (nh,) negative
 
     def step(h, inp):
-        xt, Bt, Ct, dtt = inp                            # (B,nh,hd),(B,N),(B,N),(B,nh)
+        xt, Bt, Ct, dtt = inp                            # (B,nh,hd),(B,nh,N),(B,nh,N),(B,nh)
         decay = jnp.exp(A[None] * dtt)                   # (B,nh)
         dx = (dtt[..., None] * xt).astype(jnp.float32)   # (B,nh,hd)
-        h = h * decay[..., None, None] + dx[..., None] * Bt[:, None, None, :]
-        y = jnp.einsum("bhdn,bn->bhd", h, Ct.astype(jnp.float32))
+        h = h * decay[..., None, None] + dx[..., None] * Bt[:, :, None, :]
+        y = jnp.einsum("bhdn,bhn->bhd", h, Ct.astype(jnp.float32))
         return h, y.astype(x.dtype)
 
     if h0 is None:
@@ -491,7 +517,8 @@ MAMBA_CHUNK = 128
 
 def _mamba_scan(x, B_in, C_in, dt, A_log, D, hd, *, h0=None,
                 chunk: int = MAMBA_CHUNK, unroll: bool = False):
-    """Chunkwise-parallel SSD (the Mamba2 paper's algorithm, TPU-adapted).
+    """Chunkwise-parallel SSD (the Mamba2 paper's algorithm, TPU-adapted),
+    under the named scope ``mamba_ssd``.
 
     A step-by-step scan round-trips the (B, nh, hd, N) fp32 state through
     HBM every token (memory-bound: ~7 s/step terms on the dry-run) and runs
@@ -503,102 +530,205 @@ def _mamba_scan(x, B_in, C_in, dt, A_log, D, hd, *, h0=None,
       h_out      = exp(logP_c) h_in + sum_t exp(logP_c - logP_t) u_t (x) B_t
 
     All decay ratios are exp of non-positive numbers — stable in log space.
+    B_in, C_in are (B,S,N) for all heads, or (B,S,G,N) per group, head h
+    reading group h // (nh/G); the heads are laid out as (G, nh/G) inside.
     """
-    Bb, S, nh, _ = x.shape
-    N = B_in.shape[-1]
-    if S % chunk or S <= chunk:
-        return _mamba_scan_seq(x, B_in, C_in, dt, A_log, D, hd, h0=h0)
-    A = -jnp.exp(A_log.astype(jnp.float32))              # (nh,)
-    n = S // chunk
-    f32 = jnp.float32
+    with jax.named_scope("mamba_ssd"):
+        Bb, S, nh, _ = x.shape
+        if S % chunk or S <= chunk:
+            return _mamba_scan_seq(x, B_in, C_in, dt, A_log, D, hd, h0=h0)
+        if B_in.ndim == 3:
+            B_in, C_in = B_in[:, :, None], C_in[:, :, None]
+        G, N = B_in.shape[2:]
+        R = nh // G
+        A = -jnp.exp(A_log.astype(jnp.float32)).reshape(G, R)
+        n = S // chunk
+        f32 = jnp.float32
 
-    def reshape_c(t):
-        return t.reshape(Bb, n, chunk, *t.shape[2:])
+        def reshape_c(t):
+            return t.reshape(Bb, n, chunk, *t.shape[2:])
 
-    xc = reshape_c(x)
-    Bc = reshape_c(B_in).astype(f32)
-    Cc = reshape_c(C_in).astype(f32)
-    dtc = reshape_c(dt).astype(f32)
-    u = dtc[..., None] * xc.astype(f32)                  # (B,n,c,nh,hd)
-    loga = A[None, None, None] * dtc                     # (B,n,c,nh) <= 0
-    logP = jnp.cumsum(loga, axis=2)                      # (B,n,c,nh)
-    logPc = logP[:, :, -1]                               # (B,n,nh)
+        xc = reshape_c(x).reshape(Bb, n, chunk, G, R, hd)
+        Bc = reshape_c(B_in).astype(f32)                 # (B,n,c,G,N)
+        Cc = reshape_c(C_in).astype(f32)
+        dtc = reshape_c(dt).astype(f32).reshape(Bb, n, chunk, G, R)
+        u = dtc[..., None] * xc.astype(f32)              # (B,n,c,G,R,hd)
+        loga = A * dtc                                   # (B,n,c,G,R) <= 0
+        logP = jnp.cumsum(loga, axis=2)                  # (B,n,c,G,R)
+        logPc = logP[:, :, -1]                           # (B,n,G,R)
 
-    # intra-chunk: (C_t.B_s) * exp(logP_t - logP_s), masked s <= t
-    cb = jnp.einsum("bntk,bnsk->bnts", Cc, Bc)           # (B,n,c,c)
-    ratio = logP[:, :, :, None, :] - logP[:, :, None, :, :]   # (B,n,t,s,nh)
-    mask = (jnp.arange(chunk)[:, None] >= jnp.arange(chunk)[None, :])
-    ratio = jnp.where(mask[None, None, :, :, None], ratio, -1e30)
-    y_intra = jnp.einsum("bnts,bntsh,bnshd->bnthd", cb, jnp.exp(ratio), u)
+        # intra-chunk: (C_t.B_s) * exp(logP_t - logP_s), masked s <= t
+        cb = jnp.einsum("bntgk,bnsgk->bngts", Cc, Bc)    # (B,n,G,c,c)
+        ratio = logP[:, :, :, None] - logP[:, :, None]   # (B,n,t,s,G,R)
+        mask = (jnp.arange(chunk)[:, None] >= jnp.arange(chunk)[None, :])
+        ratio = jnp.where(mask[None, None, :, :, None, None], ratio, -1e30)
+        y_intra = jnp.einsum("bngts,bntsgr,bnsgrd->bntgrd", cb,
+                             jnp.exp(ratio), u)
 
-    # chunk-boundary states via an outer scan over n chunks
-    contrib = jnp.einsum("bnth,bnthd,bntk->bnhdk",
-                         jnp.exp(logPc[:, :, None] - logP), u, Bc)
+        # chunk-boundary states via an outer scan over n chunks
+        contrib = jnp.einsum("bntgr,bntgrd,bntgk->bngrdk",
+                             jnp.exp(logPc[:, :, None] - logP), u, Bc)
 
-    if h0 is None:
-        h0 = jnp.zeros((Bb, nh, hd, N), f32)
+        h0 = (jnp.zeros((Bb, G, R, hd, N), f32) if h0 is None
+              else h0.reshape(Bb, G, R, hd, N))
 
-    def chunk_step(h, inp):
-        lpc, contr, Ct, lP = inp
-        y_cross = jnp.einsum("bth,btk,bhdk->bthd", jnp.exp(lP), Ct, h)
-        h_new = h * jnp.exp(lpc)[..., None, None] + contr
-        return h_new, y_cross
+        def chunk_step(h, inp):
+            lpc, contr, Ct, lP = inp
+            y_cross = jnp.einsum("btgr,btgk,bgrdk->btgrd", jnp.exp(lP), Ct,
+                                 h)
+            h_new = h * jnp.exp(lpc)[..., None, None] + contr
+            return h_new, y_cross
 
-    xs = (jnp.moveaxis(logPc, 1, 0), jnp.moveaxis(contrib, 1, 0),
-          jnp.moveaxis(Cc, 1, 0), jnp.moveaxis(logP, 1, 0))
-    if unroll:
-        h, ys = h0, []
-        for i in range(n):
-            h, yc = chunk_step(h, jax.tree.map(lambda t: t[i], xs))
-            ys.append(yc)
-        y_cross = jnp.stack(ys, axis=1)
-        h_fin = h
-    else:
-        h_fin, ys = lax.scan(chunk_step, h0, xs)
-        y_cross = jnp.moveaxis(ys, 0, 1)
+        xs = (jnp.moveaxis(logPc, 1, 0), jnp.moveaxis(contrib, 1, 0),
+              jnp.moveaxis(Cc, 1, 0), jnp.moveaxis(logP, 1, 0))
+        if unroll:
+            h, ys = h0, []
+            for i in range(n):
+                h, yc = chunk_step(h, jax.tree.map(lambda t: t[i], xs))
+                ys.append(yc)
+            y_cross = jnp.stack(ys, axis=1)
+            h_fin = h
+        else:
+            h_fin, ys = lax.scan(chunk_step, h0, xs)
+            y_cross = jnp.moveaxis(ys, 0, 1)
 
-    y = (y_intra + y_cross).reshape(Bb, S, nh, hd).astype(x.dtype)
-    return y + D[None, None, :, None] * x, h_fin
+        y = (y_intra + y_cross).reshape(Bb, S, nh, hd).astype(x.dtype)
+        return y + D[None, None, :, None] * x, h_fin.reshape(Bb, nh, hd, N)
 
 
 def mamba_block(p, cfg, x, *, state=None, conv_state=None,
-                return_state=False, unroll: bool = False):
-    """Mamba2 residual block.  Training/prefill path (full sequence,
-    chunkwise-parallel SSD) or, with ``state``/``conv_state``, single-token
-    decode (sequential step)."""
+                return_state=False, unroll: bool = False, extra=None):
+    """Mamba2 residual block, x + mixer(rms_norm(x)).  Training/prefill
+    path (full sequence, chunkwise-parallel SSD) or, with
+    ``state``/``conv_state``, single-token decode (sequential step).
+
+    The layout of ``p`` (:func:`mamba_defs`) says which mixer: with
+    ``conv_b``, the published grouped one.  ``extra``, where given, is
+    added to the mixer's input and not to the residual (Zamba2's hybrid
+    layer feeds its shared block's output in so)."""
     Bb, S, d = x.shape
-    e = cfg.ssm_expand * d
+    e = p["w_x"].shape[-1]
     hd = cfg.ssm_head_dim
     nh = e // hd
     W = cfg.ssm_conv_width
-    h = rms_norm(x, p["ln"], cfg.norm_eps)
+    grouped = "conv_b" in p
+    G = p["w_B"].shape[-1] // cfg.ssm_state if grouped else 1
+    h = rms_norm(x if extra is None else x + extra, p["ln"], cfg.norm_eps)
     z = jnp.einsum("bsd,de->bse", h, p["w_z"])
     xin = jnp.einsum("bsd,de->bse", h, p["w_x"])
+    B_in = jnp.einsum("bsd,dn->bsn", h, p["w_B"])
+    C_in = jnp.einsum("bsd,dn->bsn", h, p["w_C"])
+    if grouped:                                          # conv over x, B, C
+        xin = jnp.concatenate([xin, B_in, C_in], axis=-1)
     xin = shard(xin, "batch", "seq", "mlp")
+    c = xin.shape[-1]
     # causal depthwise conv
-    if conv_state is not None:                           # decode: (B, W-1, e)
-        window = jnp.concatenate([conv_state, xin], axis=1)   # (B, W, e)
+    if conv_state is not None:                           # decode: (B, W-1, c)
+        window = jnp.concatenate([conv_state, xin], axis=1)   # (B, W, c)
         new_conv = window[:, 1:]
         xc = jnp.einsum("bwe,we->be", window, p["conv_w"])[:, None]
     else:
-        pad = jnp.zeros((Bb, W - 1, e), xin.dtype)
+        pad = jnp.zeros((Bb, W - 1, c), xin.dtype)
         win = jnp.concatenate([pad, xin], axis=1)
         xc = sum(win[:, i:i + S] * p["conv_w"][i] for i in range(W))
         new_conv = win[:, S:]                            # last W-1 inputs
+    if grouped:
+        xc = xc + p["conv_b"]
     xc = jax.nn.silu(xc)
-    B_in = jnp.einsum("bsd,dn->bsn", h, p["w_B"])
-    C_in = jnp.einsum("bsd,dn->bsn", h, p["w_C"])
+    if grouped:
+        xc, B_in, C_in = jnp.split(xc, [e, e + G * cfg.ssm_state], axis=-1)
+        B_in = B_in.reshape(*B_in.shape[:2], G, -1)
+        C_in = C_in.reshape(*C_in.shape[:2], G, -1)
     dt = jax.nn.softplus(jnp.einsum("bsd,dh->bsh", h, p["w_dt"])
                          + p["dt_bias"])
     y, h_fin = _mamba_scan(xc.reshape(Bb, -1, nh, hd), B_in, C_in, dt,
                            p["A_log"], p["D"], hd, h0=state, unroll=unroll)
-    y = y.reshape(Bb, -1, e) * jax.nn.silu(z)
-    y = rms_norm(y, p["gn"], cfg.norm_eps)
-    out = jnp.einsum("bse,ed->bsd", y, p["w_out"])
+    y = (y.reshape(Bb, -1, e) * jax.nn.silu(z)).reshape(Bb, -1, G, e // G)
+    y = rms_norm(y, p["gn"].reshape(G, e // G), cfg.norm_eps)
+    out = jnp.einsum("bse,ed->bsd", y.reshape(Bb, -1, e), p["w_out"])
     out = x + shard(out, "batch", "seq", "embed")
     if return_state:
         return out, h_fin, new_conv
     return out
+
+
+# ---------------------------------------------------------------------------
+# Zamba2 hybrid layer: a shared transformer block over concat(x, embedding)
+# (arXiv:2411.15242), its adapter, and the Mamba2 layer it feeds
+# ---------------------------------------------------------------------------
+
+
+def zamba2_shared_defs(cfg) -> dict:
+    """One weight set of Zamba2's shared block: attention from the 2d-wide
+    concat(x, embedding) to the heads this chip holds, back to d; the
+    gated-GELU MLP of the columns it holds, gate and up side by side."""
+    d, H, KV, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    f = cfg.zamba2.mlp
+    return {
+        "attn": {"ln": ParamDef((2 * d,), ("embed",), init="ones"),
+                 "wq": ParamDef((2 * d, H, hd), ("fsdp", "heads", "head_dim")),
+                 "wk": ParamDef((2 * d, KV, hd),
+                                ("fsdp", "kv_heads", "head_dim")),
+                 "wv": ParamDef((2 * d, KV, hd),
+                                ("fsdp", "kv_heads", "head_dim")),
+                 "wo": ParamDef((H, hd, d), ("heads", "head_dim", "fsdp"))},
+        "mlp": {"ln": ParamDef((d,), ("embed",), init="ones"),
+                "w_gu": ParamDef((d, 2 * f), ("fsdp", "mlp")),
+                "w_down": ParamDef((f, d), ("mlp", "fsdp"))},
+    }
+
+
+def zamba2_hybrid_defs(cfg) -> dict:
+    """A hybrid layer's own weights: the LoRA adapter on the shared MLP's
+    gate and up projection, the projection of the shared block's output
+    into the mixer's input, and its Mamba2 layer."""
+    z, d, r = cfg.zamba2, cfg.d_model, ZAMBA2_ADAPTER_RANK
+    return {"mamba": mamba_defs(cfg, heads=z.ssm_heads, groups=z.ssm_groups),
+            "lora_A": ParamDef((d, r), ("fsdp", None)),
+            "lora_B": ParamDef((r, 2 * z.mlp), (None, "mlp")),
+            "w_lin": ParamDef((d, d), ("fsdp", "embed"))}
+
+
+def zamba2_attention(at, cfg, g, positions, *,
+                     unroll: bool = False) -> jax.Array:
+    """The shared block's attention of the normed concat ``g`` (B, S, 2d):
+    rotary, causal, scores scaled by (hd/2)^-1/2.  Returns (B, S, d)."""
+    q, k, v = _qkv(at, cfg, g, positions)
+    o = mha(q, k, v, causal=True, q_chunk=cfg.attn_q_chunk, unroll=unroll,
+            scale=1.0 / math.sqrt(cfg.hd / 2))
+    return jnp.einsum("bshk,hkd->bsd", o, at["wo"])
+
+
+def zamba2_mlp(mp, p, m) -> jax.Array:
+    """The shared gated-GELU MLP of the normed ``m`` with the layer's LoRA
+    adapter on its gate and up projection:
+    T = (gelu(G) * U) W_down,  [G|U] = m W_gu + (m A) B."""
+    lora = jnp.einsum("bsd,dr->bsr", m, p["lora_A"])
+    gu = (jnp.einsum("bsd,df->bsf", m, mp["w_gu"])
+          + jnp.einsum("bsr,rf->bsf", lora, p["lora_B"]))
+    gate, up = jnp.split(shard(gu, "batch", "seq", "mlp"), 2, axis=-1)
+    return jnp.einsum("bsf,fd->bsd",
+                      jax.nn.gelu(gate, approximate=False) * up, mp["w_down"])
+
+
+def zamba2_hybrid_block(p, shared, cfg, x, x0, positions, *,
+                        unroll: bool = False) -> jax.Array:
+    """Zamba2's hybrid layer, with one weight set ``shared`` of the shared
+    block and the layer's own ``p``:
+
+        g = rms_norm(concat(x, x0));  m = rms_norm(Attn(g))
+        out = x + Mamba2(rms_norm(x + T W_lin)),  T = zamba2_mlp(m)
+
+    The Mamba2 layer runs under the named scope ``mamba2``."""
+    g = rms_norm(jnp.concatenate([x, x0], axis=-1), shared["attn"]["ln"],
+                 cfg.norm_eps)
+    m = rms_norm(zamba2_attention(shared["attn"], cfg, g, positions,
+                                  unroll=unroll),
+                 shared["mlp"]["ln"], cfg.norm_eps)
+    t = jnp.einsum("bsd,de->bse", zamba2_mlp(shared["mlp"], p, m),
+                   p["w_lin"])
+    with jax.named_scope("mamba2"):
+        return mamba_block(p["mamba"], cfg, x, extra=t, unroll=unroll)
 
 
 # ---------------------------------------------------------------------------

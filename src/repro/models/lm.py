@@ -13,6 +13,13 @@ Block kinds (config.block_pattern):
   mlstm/slstm — xLSTM blocks
   shared_attn — zamba2-style shared transformer block (one weight set reused
                 at every occurrence, per-occurrence input adapter)
+  mamba2      — Zamba2's Mamba2 layer: grouped B and C, the convolution over
+                x, B and C with bias, the gated norm per group
+  zamba2_hybrid — Zamba2's hybrid layer: one of the shared blocks (picked by
+                the cycle's index) over concat(x, embedding), its LoRA
+                adapter and output projection, then its Mamba2 layer
+
+The Zamba2 kinds are training paths: they have no decode cache.
 """
 
 from __future__ import annotations
@@ -31,6 +38,9 @@ from repro.models.config import ArchConfig
 from repro.parallel.axes import shard
 
 Pytree = Any
+
+# block kinds with no decode cache: prefill and decode refuse them
+TRAINING_ONLY = frozenset({"mamba2", "zamba2_hybrid"})
 
 
 def _tree_index(tree: Pytree, i) -> Pytree:
@@ -82,6 +92,12 @@ class LM:
         if kind == "shared_attn":
             return {"in_proj": L.ParamDef(
                 (cfg.d_model, cfg.d_model), ("fsdp", "embed"), scale=0.02)}
+        if kind == "mamba2":
+            z = cfg.zamba2
+            return {"mamba": L.mamba_defs(cfg, heads=z.ssm_heads,
+                                          groups=z.ssm_groups)}
+        if kind == "zamba2_hybrid":
+            return L.zamba2_hybrid_defs(cfg)
         raise ValueError(f"unknown block kind {kind}")
 
     def param_defs(self) -> dict:
@@ -96,6 +112,9 @@ class LM:
         if "shared_attn" in cfg.pattern:
             defs["shared"] = {"attn": L.attn_defs(cfg),
                               "ffn": L.ffn_defs(cfg)}
+        if "zamba2_hybrid" in cfg.pattern:
+            defs["shared"] = L.stack_defs(L.zamba2_shared_defs(cfg),
+                                          cfg.zamba2.sets)
         if cfg.encoder_layers:
             defs["encoder"] = L.stack_defs(
                 {"attn": L.attn_defs(cfg), "ffn": L.ffn_defs(cfg)},
@@ -157,13 +176,20 @@ class LM:
         decode cache when ``return_cache`` (prefill path)."""
         cfg = self.cfg
         B, S = tokens.shape
+        hybrid = "zamba2_hybrid" in cfg.pattern
+        if return_cache:
+            self._check_decode()
         with jax.named_scope("head"):
             x = L.embed(params["embed"], cfg, tokens)
+        x0 = x
         positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
         memory = self._memory(params, audio_embed, vision_embed)
         shared = params.get("shared")
 
-        def cycle(x, cyc_params):
+        def cycle(carry, cyc_params):
+            # a hybrid pattern carries the cycle's index beside x, to pick
+            # its shared set: set c mod sets
+            x, c = carry if hybrid else (carry, None)
             cache_out = []
             for p, kind in enumerate(cfg.pattern):
                 bp = cyc_params[f"pos{p}"]
@@ -209,6 +235,13 @@ class LM:
                                                     unroll=self.unroll)
                         if return_cache:
                             cache_out.append({"ssm": st, "conv": conv})
+                    elif kind == "mamba2":
+                        x = L.mamba_block(bp["mamba"], cfg, x,
+                                          unroll=self.unroll)
+                    elif kind == "zamba2_hybrid":
+                        x = L.zamba2_hybrid_block(
+                            bp, self._shared_set(shared, c), cfg, x, x0,
+                            positions, unroll=self.unroll)
                     elif kind == "mlstm":
                         x, st = L.mlstm_block(bp["mlstm"], cfg, x,
                                               return_state=True,
@@ -220,7 +253,7 @@ class LM:
                                               return_state=True)
                         if return_cache:
                             cache_out.append({"state": st})
-            return x, tuple(cache_out)
+            return ((x, c + 1) if hybrid else x), tuple(cache_out)
 
         body = cycle
         if remat == "full":
@@ -233,12 +266,21 @@ class LM:
 
         stacks = {f"pos{p}": params[f"pos{p}"]
                   for p in range(len(cfg.pattern))}
-        x, caches = self._scan(body, x, stacks)
+        init = (x, jnp.zeros((), jnp.int32)) if hybrid else x
+        out, caches = self._scan(body, init, stacks)
+        x = out[0] if hybrid else out
         with jax.named_scope("head"):
             x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
         if return_cache:
             return x, caches
         return x
+
+    def _shared_set(self, shared: Pytree, c: jax.Array) -> Pytree:
+        """The shared weight set of cycle ``c``: set c mod sets."""
+        sets = self.cfg.zamba2.sets
+        return jax.tree.map(
+            lambda a: lax.dynamic_index_in_dim(a, c % sets, keepdims=False),
+            shared)
 
     # ------------------------------------------------------------------
     # Losses / serving entry points
@@ -263,6 +305,13 @@ class LM:
     # per pattern position): each entry is an independent buffer, so XLA
     # aliases the donated input cache in place — no double-buffering through
     # a scan's ys.  decode_step unrolls the (cheap per-layer) decode HLO.
+
+    def _check_decode(self) -> None:
+        untrained = sorted(set(self.cfg.pattern) & TRAINING_ONLY)
+        if untrained:
+            raise NotImplementedError(
+                f"{self.cfg.name}: block kinds {untrained} have no decode "
+                f"cache (training only)")
 
     def _cache_entry(self, kind: str, batch: int, max_len: int, mk):
         cfg = self.cfg
@@ -299,6 +348,7 @@ class LM:
         """Zeroed (or abstract) flat per-layer decode cache.  The xLSTM
         max-stabilizer states start at -1e30 (matching the blocks' internal
         init), everything else at zero."""
+        self._check_decode()
         mk = (lambda s, d, fill=0.0: jax.ShapeDtypeStruct(s, d)) if abstract \
             else (lambda s, d, fill=0.0: jnp.full(s, fill, d))
         return tuple(self._cache_entry(self.cfg.block_kind(i), batch,
@@ -371,6 +421,7 @@ class LM:
         ``cache`` is the flat per-layer tuple; pass it donated so every
         layer's k/v/state updates alias in place.
         """
+        self._check_decode()
         cfg = self.cfg
         x = L.embed(params["embed"], cfg, tokens)
         memory = self._memory(params, audio_embed, vision_embed)

@@ -27,7 +27,9 @@ place the batch), ``trainer.compile`` (attribute ``step``),
 ``block_until_ready``), ``trainer.ckpt`` (a periodic save's submit), and
 per event ``trainer.event`` (attribute ``kind``) around
 ``trainer.event.save``, ``.replan``, ``.rebuild`` and ``.restore``.  The
-timings above are those spans' own clock reads.
+timings above are those spans' own clock reads.  A log step other than
+the run's last is synced once the next step is dispatched, so the device
+is not left idle while the host reads and prints it.
 """
 
 from __future__ import annotations
@@ -268,7 +270,15 @@ class Trainer:
         self._hist_mark = len(self.history)
         ev_i = 0
         t0 = time.perf_counter()
+        # a log step other than the last is read once the next step is
+        # dispatched, so that the device has work queued while the host
+        # waits for the logged step and prints it
+        pending = None
         for step in range(start_step, cfg.steps):
+            if pending and ev_i < len(self.events) \
+                    and self.events[ev_i][0] == step:
+                self._log(t0, *pending)
+                pending = None
             while ev_i < len(self.events) and self.events[ev_i][0] == step:
                 _, ev = self.events[ev_i]
                 state = self._handle_event(step, ev, state)
@@ -281,17 +291,13 @@ class Trainer:
                 self.compile_s.append(compiling.seconds)
             with obs.timed("trainer.dispatch") as dispatch:
                 state, metrics = self._compiled(state, batch)
-            if step % cfg.log_every == 0 or step == cfg.steps - 1:
-                with obs.timed("trainer.sync") as sync:
-                    jax.block_until_ready((state, metrics))
-                m = {k: float(v) for k, v in metrics.items()}
-                m.update(step=step, wall=sync.t1 - t0,
-                         step_s=sync.t1 - dispatch.t0)
-                self.history.append(m)
-                tok_s = m["tokens"] * (step - start_step + 1) / m["wall"]
-                print(f"  step {step:4d} loss {m['loss']:.4f} "
-                      f"gnorm {m['grad_norm']:.2f} lr {m['lr']:.2e} "
-                      f"tok/s {tok_s:,.0f}", flush=True)
+            if pending:
+                self._log(t0, *pending)
+                pending = None
+            if step == cfg.steps - 1:
+                self._log(t0, step, metrics, dispatch.t0, state)
+            elif step % cfg.log_every == 0:
+                pending = (step, metrics, dispatch.t0)
             if cfg.ckpt_every and step and step % cfg.ckpt_every == 0:
                 with obs.span("trainer.ckpt"):
                     self.saver.submit(Path(cfg.ckpt_dir) / f"step_{step}",
@@ -300,3 +306,18 @@ class Trainer:
                                       if self.plan else "")
         self.saver.wait()
         return state, self.history
+
+    def _log(self, t0: float, step: int, metrics: dict, dispatched: float,
+             state: Pytree = None) -> None:
+        """Wait for the step's metrics (and ``state``, the last step's),
+        then record and print them; ``step_s`` runs from its dispatch."""
+        with self.obs.timed("trainer.sync") as sync:
+            jax.block_until_ready((state, metrics))
+        m = {k: float(v) for k, v in jax.device_get(metrics).items()}
+        m.update(step=step, wall=sync.t1 - t0,
+                 step_s=sync.t1 - dispatched)
+        self.history.append(m)
+        tok_s = m["tokens"] * (step - self._start_step + 1) / m["wall"]
+        print(f"  step {step:4d} loss {m['loss']:.4f} "
+              f"gnorm {m['grad_norm']:.2f} lr {m['lr']:.2e} "
+              f"tok/s {tok_s:,.0f}", flush=True)

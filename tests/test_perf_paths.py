@@ -86,6 +86,38 @@ def test_chunkwise_ssd_gradients_match():
                                atol=2e-3, rtol=2e-2)
 
 
+@pytest.mark.parametrize("groups", [1, 2])
+def test_grouped_chunkwise_ssd_matches_sequential(groups):
+    """B and C per group, head h reading group h // (nh / G): the chunkwise
+    SSD against the step-by-step recurrence with each head's B and C
+    written out, values and gradients."""
+    B, S, nh, hd, N = 2, 256, 4, 8, 8
+    ks = jax.random.split(jax.random.PRNGKey(4), 5)
+    x = jax.random.normal(ks[0], (B, S, nh, hd))
+    B_in = jax.random.normal(ks[1], (B, S, groups, N)) * 0.5
+    C_in = jax.random.normal(ks[2], (B, S, groups, N)) * 0.5
+    dt = jax.nn.softplus(jax.random.normal(ks[3], (B, S, nh)))
+    A_log = jax.random.normal(ks[4], (nh,)) * 0.3
+    D = jnp.linspace(0.5, 1.5, nh)
+    per_head = [h * groups // nh for h in range(nh)]
+
+    def by_head(x):
+        ys = [L._mamba_scan_seq(x[:, :, h:h + 1], B_in[:, :, g],
+                                C_in[:, :, g], dt[:, :, h:h + 1],
+                                A_log[h:h + 1], D[h:h + 1], hd)[0]
+              for h, g in enumerate(per_head)]
+        return jnp.concatenate(ys, axis=2)
+
+    def chunked(x):
+        return L._mamba_scan(x, B_in, C_in, dt, A_log, D, hd, chunk=64)[0]
+
+    np.testing.assert_allclose(chunked(x), by_head(x), atol=5e-4, rtol=5e-3)
+    np.testing.assert_allclose(
+        jax.grad(lambda x: jnp.sum(chunked(x) ** 2))(x),
+        jax.grad(lambda x: jnp.sum(by_head(x) ** 2))(x), atol=2e-3,
+        rtol=2e-2)
+
+
 def test_chunked_time_scan_matches_plain():
     def step(c, x):
         c = 0.9 * c + x

@@ -204,10 +204,14 @@ def test_trainer_spans_are_its_timings(tmp_path):
     assert len(by["trainer.ckpt"]) == 1             # step 5, ckpt_every 5
     syncs = by["trainer.sync"]
     assert len(syncs) == len(hist) == 4             # steps 0, 2, 4, 5
+    dispatches = by["trainer.dispatch"]             # one per step, in order
     for h, sync in zip(hist, syncs):
-        last = max((d for d in by["trainer.dispatch"] if d.t1 <= sync.t0),
-                   key=lambda d: d.t0)
-        assert h["step_s"] == sync.t1 - last.t0
+        assert h["step_s"] == sync.t1 - dispatches[h["step"]].t0
+    # a log step before the last is synced once the next step is
+    # dispatched, but before an event that is due next (step 2: the event
+    # at 3 reads the history)
+    assert [max(i for i, d in enumerate(dispatches) if d.t1 <= sync.t0)
+            for sync in syncs] == [1, 2, 5, 5]
     (event,) = by["trainer.event"]
     assert event.attrs == {"kind": "bandwidth"}
     for part in ("save", "replan", "rebuild", "restore"):
